@@ -11,11 +11,12 @@ import jax
 
 from benchmarks.common import emit
 from repro.core import linktest
+from repro.launch.mesh import mesh_from_spec
 
 
 def main():
     n = len(jax.devices())
-    mesh = jax.make_mesh((n,), ("model",))
+    mesh = mesh_from_spec(str(n))
     for payload in (1 << 12, 1 << 16, 1 << 20):
         reports = linktest.run_link_test(mesh, payload_bytes=payload)
         for r in reports:

@@ -669,6 +669,8 @@ if __name__ == "__main__":
                          "section into BENCH_serve.json (skips the plain "
                          "sections)")
     ns = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if ns.mesh:
         main_mesh(ns.mesh, smoke=ns.smoke)
     elif ns.scheduler:

@@ -137,6 +137,8 @@ if __name__ == "__main__":
                          "kernel dispatch and merge a 'mesh' section into "
                          "BENCH_step.json (skips the plain sections)")
     ns = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if ns.mesh:
         main_mesh(ns.mesh, smoke=ns.smoke)
     else:

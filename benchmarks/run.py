@@ -11,6 +11,8 @@ import traceback
 
 
 def main() -> None:
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from benchmarks import (bench_collectives, bench_linktest, bench_memtest,
                             bench_roofline, bench_serve, bench_step)
     sections = [

@@ -27,6 +27,7 @@ from repro.configs import get_smoke_config                # noqa: E402
 from repro.core.topology import make_plan                 # noqa: E402
 from repro.data.pipeline import DataConfig, synthetic_batch  # noqa: E402
 from repro.ft.elastic import make_elastic_mesh, plan_remesh  # noqa: E402
+from repro.launch.mesh import mesh_from_spec              # noqa: E402
 from repro.optim.schedules import make_schedule           # noqa: E402
 from repro.runtime import Runtime                         # noqa: E402
 
@@ -73,7 +74,7 @@ def main():
                       global_batch=GLOBAL_BATCH, branch=4)
 
     print("phase 1: healthy mesh (4 data x 2 model), 15 steps")
-    mesh1 = jax.make_mesh((4, 2), ("data", "model"))
+    mesh1 = mesh_from_spec("4x2")
     losses1, last = run_phase(mesh1, cfg, dcfg, steps=15, start=0,
                               microbatches=1, restore=False)
     print(f"  loss {losses1[0]:.3f} -> {losses1[-1]:.3f}")

@@ -22,6 +22,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 import jax                                               # noqa: E402
 
 from repro.configs import get_config                      # noqa: E402
+from repro.launch.mesh import mesh_from_spec              # noqa: E402
 from repro.launch.train import train_loop                 # noqa: E402
 
 
@@ -40,10 +41,10 @@ def main():
     cfg = get_config("exanode-100m")
     n = len(jax.devices())
     if args.distributed and n >= 8:
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        mesh = mesh_from_spec("2x2x2")
         sync = "hierarchical_int8"
     else:
-        mesh = jax.make_mesh((1, min(n, 1)), ("data", "model"))
+        mesh = mesh_from_spec("1x1")
         sync = "hierarchical"
     train_loop(cfg, mesh, steps=args.steps, global_batch=args.batch,
                seq_len=args.seq, grad_sync=sync,

@@ -26,7 +26,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import axis_size, shard_map
 from repro.core import compression
 
 
@@ -47,7 +46,7 @@ def hierarchical_psum(x: jax.Array, fast_axis: str, slow_axis: str) -> jax.Array
     Must run inside a shard_map where both axes are manual.  The leading dim
     of ``x`` must be divisible by the fast-axis size.
     """
-    p_fast = axis_size(fast_axis)
+    p_fast = jax.lax.axis_size(fast_axis)
     lead = x.shape[0]
     assert lead % p_fast == 0, (lead, p_fast)
     shard = jax.lax.psum_scatter(x, fast_axis, scatter_dimension=0, tiled=True)
@@ -74,7 +73,7 @@ def pod_manual(fn: Callable, mesh, in_specs, out_specs,
     across pods, P('pod') = split).  Inside ``fn`` the model's
     with_sharding_constraint annotations over 'data'/'model' keep working.
     """
-    return shard_map(fn, mesh=mesh, in_specs=in_specs,
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
                          out_specs=out_specs, axis_names={pod_axis},
                          check_vma=False)
 
@@ -89,7 +88,7 @@ def sync_grads_over_pod(grads, *, pod_axis: str = "pod",
     (residual pytree threaded through the train state); cross-pod bytes
     drop ~4x.  Returns (synced_grads, new_residual).
     """
-    npods = axis_size(pod_axis)
+    npods = jax.lax.axis_size(pod_axis)
     if not compress:
         synced = jax.tree.map(
             lambda g: jax.lax.psum(g, pod_axis) / npods, grads)

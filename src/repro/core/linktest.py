@@ -24,7 +24,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
-from repro.compat import axis_size, shard_map
 
 # ---------------------------------------------------------------------------
 # PRBS-31 generator (x^31 + x^28 + 1, the polynomial IBERT uses)
@@ -109,7 +108,7 @@ def _axis_exercises(payload: jax.Array, axis: str):
     """Runs inside shard_map (manual over ``axis``).  Each device holds the
     same PRBS payload; exercises the axis with the collectives the framework
     uses and returns bit-error counts per exercise."""
-    p = axis_size(axis)
+    p = jax.lax.axis_size(axis)
     idx = jax.lax.axis_index(axis)
 
     # 1. all-gather: every device must receive every other device's payload
@@ -161,7 +160,7 @@ def run_link_test(mesh, payload_bytes: int = 1 << 16,
         # only issues collectives over ``axis``, so the semantics are the
         # same, and full-manual avoids the partial-manual PartitionId path
         # older XLA cannot partition
-        fn = shard_map(
+        fn = jax.shard_map(
             lambda x, a=axis: _axis_exercises(x, a),
             mesh=mesh, in_specs=P(), out_specs=P(),
             axis_names=set(mesh.axis_names), check_vma=False)

@@ -33,6 +33,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
@@ -40,14 +41,20 @@ DEFAULT_BQ = 256
 DEFAULT_BK = 512
 
 
-def _block_mask(q_pos, kv_pos, causal: bool, window: int):
-    """[bq,bk] boolean; True = attend.  Mirrors models.attention._mask for
-    standard arange positions."""
+def _block_mask(q0, k0, bq: int, bk: int, causal: bool, window: int,
+                transposed: bool = False):
+    """[bq,bk] boolean ([bk,bq] when ``transposed``) for the tile whose
+    first q row is ``q0`` and first kv column is ``k0``; True = attend.
+    Mirrors models.attention._mask for standard arange positions.  Built
+    from 2-D iotas (TPU has no 1-D iota)."""
     if not causal:
         return None
-    mask = kv_pos[None, :] <= q_pos[:, None]
+    shape, qd = ((bk, bq), 1) if transposed else ((bq, bk), 0)
+    q_pos = q0 + jax.lax.broadcasted_iota(jnp.int32, shape, qd)
+    kv_pos = k0 + jax.lax.broadcasted_iota(jnp.int32, shape, 1 - qd)
+    mask = kv_pos <= q_pos
     if window > 0:
-        mask &= kv_pos[None, :] > (q_pos[:, None] - window)
+        mask &= kv_pos > (q_pos - window)
     return mask
 
 
@@ -59,82 +66,105 @@ def _first_kv_block(iq, bq: int, bk: int, causal: bool, window: int):
     return jnp.maximum(0, (iq * bq - window + 1) // bk)
 
 
+def _last_kv_block(iq, bq: int, bk: int, T: int, causal: bool):
+    """One past the last KV block q block ``iq`` can see."""
+    nkv = T // bk
+    if causal:
+        nkv = jnp.minimum(nkv, ((iq + 1) * bq - 1) // bk + 1)
+    return nkv
+
+
+def _dot(a, b, contract):
+    """MXU matmul in the operands' dtype with f32 accumulation."""
+    return jax.lax.dot_general(a, b, (contract, ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def _row(col):
+    """[n,1] column -> [1,n] row (lane-dense) via one aligned 2-D
+    transpose; the softmax-stat residuals are stored as rows."""
+    n = col.shape[0]
+    return jnp.transpose(jnp.broadcast_to(col, (n, 128)))[:1]
+
+
+def _col(row):
+    """[1,n] row -> [n,1] column (inverse of :func:`_row`)."""
+    n = row.shape[1]
+    return jnp.transpose(jnp.broadcast_to(row, (8, n)))[:, :1]
+
+
 # ---------------------------------------------------------------------------
 # Forward
 # ---------------------------------------------------------------------------
 
 
-def _attn_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, bq: int, bk: int,
+def _attn_kernel(q_ref, k_ref, v_ref, o_ref, *lse_ref, bq: int, bk: int,
                  scale: float, causal: bool, window: int):
-    """One (b, h, q-block) step.  q_ref [bq,d]; k_ref/v_ref [T,d] (HBM-to-
-    VMEM streamed in bk slices); o_ref [bq,d]; lse_ref [bq] (softmax stats
-    residual for the backward)."""
+    """One (b, h, q-block) step.  q_ref [bq,d]; k_ref/v_ref [T,d] (streamed
+    through the MXU in bk slices); o_ref [bq,d]; optional lse_ref [1,bq]
+    (softmax-stats residual for the backward, stored lane-dense)."""
     iq = pl.program_id(2)
     T = k_ref.shape[0]
     d = q_ref.shape[-1]
-    q = q_ref[...].astype(jnp.float32) * scale
-    q_pos = iq * bq + jax.lax.iota(jnp.int32, bq)
-
-    nkv = T // bk
-    if causal:
-        # only blocks whose first row index <= last q position
-        last_q = (iq + 1) * bq - 1
-        nkv = jnp.minimum(nkv, (last_q // bk) + 1)
+    q = q_ref[...]
 
     def body(j, carry):
         m, l, acc = carry
-        kb = k_ref[pl.ds(j * bk, bk), :].astype(jnp.float32)
-        vb = v_ref[pl.ds(j * bk, bk), :].astype(jnp.float32)
-        s = jax.lax.dot_general(q, kb, (((1,), (1,)), ((), ())))  # [bq,bk]
-        kv_pos = j * bk + jax.lax.iota(jnp.int32, bk)
-        mask = _block_mask(q_pos, kv_pos, causal, window)
+        kb = k_ref[pl.ds(j * bk, bk), :]
+        vb = v_ref[pl.ds(j * bk, bk), :]
+        s = _dot(q, kb, ((1,), (1,))) * scale                    # [bq,bk]
+        mask = _block_mask(iq * bq, j * bk, bq, bk, causal, window)
         if mask is not None:
             s = jnp.where(mask, s, NEG_INF)
-        m_new = jnp.maximum(m, jnp.max(s, axis=1))
-        p = jnp.exp(s - m_new[:, None])
+        m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
         corr = jnp.exp(m - m_new)
-        l_new = l * corr + jnp.sum(p, axis=1)
-        acc_new = acc * corr[:, None] + jax.lax.dot_general(
-            p, vb, (((1,), (0,)), ((), ())))
+        l_new = l * corr + jnp.sum(p, axis=1, keepdims=True)
+        acc_new = acc * corr + _dot(p.astype(vb.dtype), vb, ((1,), (0,)))
         return m_new, l_new, acc_new
 
-    m0 = jnp.full((bq,), -jnp.inf, jnp.float32)
-    l0 = jnp.zeros((bq,), jnp.float32)
+    m0 = jnp.full((bq, 1), -jnp.inf, jnp.float32)
+    l0 = jnp.zeros((bq, 1), jnp.float32)
     a0 = jnp.zeros((bq, d), jnp.float32)
-    j0 = _first_kv_block(iq, bq, bk, causal, window)
-    m, l, acc = jax.lax.fori_loop(j0, nkv, body, (m0, l0, a0))
-    o_ref[...] = (acc / jnp.maximum(l, 1e-30)[:, None]).astype(o_ref.dtype)
-    # lse on the *scaled* scores; fully-masked rows (l == 0, never produced
-    # by the model paths) get 0.0 so the backward's exp(s - lse) stays 0
-    lse_ref[...] = jnp.where(l > 0, m + jnp.log(jnp.maximum(l, 1e-30)), 0.0)
+    m, l, acc = jax.lax.fori_loop(
+        _first_kv_block(iq, bq, bk, causal, window),
+        _last_kv_block(iq, bq, bk, T, causal), body, (m0, l0, a0))
+    o_ref[...] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+    if lse_ref:
+        # lse on the *scaled* scores; fully-masked rows (l == 0, never
+        # produced by the model paths) get 0.0 so the backward's
+        # exp(s - lse) stays 0
+        lse = jnp.where(l > 0, m + jnp.log(jnp.maximum(l, 1e-30)), 0.0)
+        lse_ref[0][...] = _row(lse)
 
 
-def _forward(q, k, v, causal, window, bq, bk, interpret):
-    """Returns (out, lse); lse [B,H,S] float32."""
+def _forward(q, k, v, causal, window, bq, bk, interpret, with_lse=True):
+    """Returns (out, lse) with lse [B,H,1,S] float32, or out alone when
+    ``with_lse`` is False (inference never writes the residual)."""
     B, H, S, D = q.shape
     T = k.shape[2]
     scale = D ** -0.5
-    grid = (B, H, S // bq)
     kernel = functools.partial(_attn_kernel, bq=bq, bk=bk, scale=scale,
                                causal=causal, window=window)
-    return pl.pallas_call(
+    out_specs = [pl.BlockSpec((None, None, bq, D), lambda b, h, i: (b, h, i, 0))]
+    out_shape = [jax.ShapeDtypeStruct((B, H, S, D), q.dtype)]
+    if with_lse:
+        out_specs.append(pl.BlockSpec((None, None, 1, bq),
+                                      lambda b, h, i: (b, h, 0, i)))
+        out_shape.append(jax.ShapeDtypeStruct((B, H, 1, S), jnp.float32))
+    res = pl.pallas_call(
         kernel,
-        grid=grid,
+        grid=(B, H, S // bq),
         in_specs=[
             pl.BlockSpec((None, None, bq, D), lambda b, h, i: (b, h, i, 0)),
             pl.BlockSpec((None, None, T, D), lambda b, h, i: (b, h, 0, 0)),
             pl.BlockSpec((None, None, T, D), lambda b, h, i: (b, h, 0, 0)),
         ],
-        out_specs=[
-            pl.BlockSpec((None, None, bq, D), lambda b, h, i: (b, h, i, 0)),
-            pl.BlockSpec((None, None, bq), lambda b, h, i: (b, h, i)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B, H, S, D), q.dtype),
-            jax.ShapeDtypeStruct((B, H, S), jnp.float32),
-        ],
+        out_specs=out_specs,
+        out_shape=out_shape,
         interpret=interpret,
     )(q, k, v)
+    return res if with_lse else res[0]
 
 
 # ---------------------------------------------------------------------------
@@ -148,87 +178,91 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, *,
     iq = pl.program_id(2)
     T = k_ref.shape[0]
     d = q_ref.shape[-1]
-    q = q_ref[...].astype(jnp.float32) * scale
-    do = do_ref[...].astype(jnp.float32)
-    lse = lse_ref[...].astype(jnp.float32)
-    delta = delta_ref[...].astype(jnp.float32)
-    q_pos = iq * bq + jax.lax.iota(jnp.int32, bq)
-
-    nkv = T // bk
-    if causal:
-        last_q = (iq + 1) * bq - 1
-        nkv = jnp.minimum(nkv, (last_q // bk) + 1)
+    q = q_ref[...]
+    do = do_ref[...]
+    lse = _col(lse_ref[...])                                     # [bq,1]
+    delta = _col(delta_ref[...])
 
     def body(j, acc):
-        kb = k_ref[pl.ds(j * bk, bk), :].astype(jnp.float32)
-        vb = v_ref[pl.ds(j * bk, bk), :].astype(jnp.float32)
-        s = jax.lax.dot_general(q, kb, (((1,), (1,)), ((), ())))  # [bq,bk]
-        kv_pos = j * bk + jax.lax.iota(jnp.int32, bk)
-        mask = _block_mask(q_pos, kv_pos, causal, window)
-        p = jnp.exp(s - lse[:, None])
+        kb = k_ref[pl.ds(j * bk, bk), :]
+        vb = v_ref[pl.ds(j * bk, bk), :]
+        s = _dot(q, kb, ((1,), (1,))) * scale                    # [bq,bk]
+        p = jnp.exp(s - lse)
+        mask = _block_mask(iq * bq, j * bk, bq, bk, causal, window)
         if mask is not None:
             p = jnp.where(mask, p, 0.0)
-        dp = jax.lax.dot_general(do, vb, (((1,), (1,)), ((), ())))  # [bq,bk]
-        ds = p * (dp - delta[:, None])
-        return acc + jax.lax.dot_general(ds, kb, (((1,), (0,)), ((), ())))
+        dp = _dot(do, vb, ((1,), (1,)))                          # [bq,bk]
+        ds = p * (dp - delta)
+        return acc + _dot(ds.astype(kb.dtype), kb, ((1,), (0,)))
 
-    j0 = _first_kv_block(iq, bq, bk, causal, window)
-    acc = jax.lax.fori_loop(j0, nkv, body, jnp.zeros((bq, d), jnp.float32))
+    acc = jax.lax.fori_loop(
+        _first_kv_block(iq, bq, bk, causal, window),
+        _last_kv_block(iq, bq, bk, T, causal), body,
+        jnp.zeros((bq, d), jnp.float32))
     dq_ref[...] = (acc * scale).astype(dq_ref.dtype)
 
 
+def _dkv_live(i, j, bq: int, bk: int, causal: bool, window: int):
+    """Whether q block ``i`` contributes to kv block ``j``: on/after the
+    causal diagonal and, with SWA, not past the window."""
+    live = jnp.bool_(True)
+    if causal:
+        live &= i >= (j * bk) // bq
+        if window > 0:
+            # q rows with q_pos > max(kv_pos) + window - 1 are fully masked
+            live &= i < ((j + 1) * bk + window - 2) // bq + 1
+    return live
+
+
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                    dk_ref, dv_ref, *, bq: int, bk: int, scale: float,
-                    causal: bool, window: int):
-    """dK/dV for one (b, h, kv-block): stream Q blocks from the causal
-    diagonal down, recompute p from lse."""
+                    dk_ref, dv_ref, dk_acc, dv_acc, *, bq: int, bk: int,
+                    scale: float, causal: bool, window: int):
+    """dK/dV for one (b, h, kv-block, q-block) step.  The q block is the
+    minor grid axis; dK/dV accumulate in VMEM scratch and are emitted on
+    the last q block.  Works in the transposed [bk,bq] orientation so the
+    lane-dense lse/delta rows broadcast without a transpose."""
     j = pl.program_id(2)
-    S = q_ref.shape[0]
-    d = k_ref.shape[-1]
-    kb = k_ref[...].astype(jnp.float32)
-    vb = v_ref[...].astype(jnp.float32)
-    kv_pos = j * bk + jax.lax.iota(jnp.int32, bk)
+    i = pl.program_id(3)
 
-    nq = S // bq
-    i0 = (j * bk) // bq if causal else 0   # first q block on/after diagonal
-    if causal and window > 0:
-        # last q block still inside the window of this kv block: q rows with
-        # q_pos > max(kv_pos) + window - 1 are fully masked
-        nq = jnp.minimum(nq, ((j + 1) * bk + window - 2) // bq + 1)
+    @pl.when(i == 0)
+    def _init():
+        dk_acc[...] = jnp.zeros_like(dk_acc)
+        dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    def body(i, carry):
-        dk, dv = carry
-        qb = q_ref[pl.ds(i * bq, bq), :].astype(jnp.float32) * scale
-        dob = do_ref[pl.ds(i * bq, bq), :].astype(jnp.float32)
-        lseb = lse_ref[pl.ds(i * bq, bq)].astype(jnp.float32)
-        deltab = delta_ref[pl.ds(i * bq, bq)].astype(jnp.float32)
-        s = jax.lax.dot_general(qb, kb, (((1,), (1,)), ((), ())))  # [bq,bk]
-        q_pos = i * bq + jax.lax.iota(jnp.int32, bq)
-        mask = _block_mask(q_pos, kv_pos, causal, window)
-        p = jnp.exp(s - lseb[:, None])
+    @pl.when(_dkv_live(i, j, bq, bk, causal, window))
+    def _step():
+        kb = k_ref[...]
+        vb = v_ref[...]
+        qb = q_ref[...]
+        dob = do_ref[...]
+        st = _dot(kb, qb, ((1,), (1,))) * scale                  # [bk,bq]
+        pt = jnp.exp(st - lse_ref[...])
+        mask = _block_mask(i * bq, j * bk, bq, bk, causal, window,
+                           transposed=True)
         if mask is not None:
-            p = jnp.where(mask, p, 0.0)
-        dv = dv + jax.lax.dot_general(p, dob, (((0,), (0,)), ((), ())))
-        dp = jax.lax.dot_general(dob, vb, (((1,), (1,)), ((), ())))
-        ds = p * (dp - deltab[:, None])
-        # s = (q*scale)·k, so ∂s/∂k is the *scaled* q rows (qb)
-        dk = dk + jax.lax.dot_general(ds, qb, (((0,), (0,)), ((), ())))
-        return dk, dv
+            pt = jnp.where(mask, pt, 0.0)
+        dv_acc[...] += _dot(pt.astype(dob.dtype), dob, ((1,), (0,)))
+        dpt = _dot(vb, dob, ((1,), (1,)))                        # [bk,bq]
+        dst = pt * (dpt - delta_ref[...])
+        # s = (q·k)*scale, so ∂s/∂k is the *scaled* q rows
+        dk_acc[...] += _dot(dst.astype(qb.dtype), qb, ((1,), (0,))) * scale
 
-    z = jnp.zeros((bk, d), jnp.float32)
-    dk, dv = jax.lax.fori_loop(i0, nq, body, (z, z))
-    dk_ref[...] = dk.astype(dk_ref.dtype)
-    dv_ref[...] = dv.astype(dv_ref.dtype)
+    @pl.when(i == pl.num_programs(3) - 1)
+    def _emit():
+        dk_ref[...] = dk_acc[...].astype(dk_ref.dtype)
+        dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
 
 
 def _backward(q, k, v, o, lse, g, causal, window, bq, bk, interpret):
     B, H, S, D = q.shape
     T = k.shape[2]
     scale = D ** -0.5
-    delta = jnp.sum(g.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
+    delta = jnp.sum(g.astype(jnp.float32) * o.astype(jnp.float32),
+                    axis=-1)[:, :, None, :]                      # [B,H,1,S]
 
     dq_kernel = functools.partial(_bwd_dq_kernel, bq=bq, bk=bk, scale=scale,
                                   causal=causal, window=window)
+    row_spec = pl.BlockSpec((None, None, 1, bq), lambda b, h, i: (b, h, 0, i))
     dq = pl.pallas_call(
         dq_kernel,
         grid=(B, H, S // bq),
@@ -237,8 +271,8 @@ def _backward(q, k, v, o, lse, g, causal, window, bq, bk, interpret):
             pl.BlockSpec((None, None, T, D), lambda b, h, i: (b, h, 0, 0)),
             pl.BlockSpec((None, None, T, D), lambda b, h, i: (b, h, 0, 0)),
             pl.BlockSpec((None, None, bq, D), lambda b, h, i: (b, h, i, 0)),
-            pl.BlockSpec((None, None, bq), lambda b, h, i: (b, h, i)),
-            pl.BlockSpec((None, None, bq), lambda b, h, i: (b, h, i)),
+            row_spec,
+            row_spec,
         ],
         out_specs=pl.BlockSpec((None, None, bq, D),
                                lambda b, h, i: (b, h, i, 0)),
@@ -248,25 +282,21 @@ def _backward(q, k, v, o, lse, g, causal, window, bq, bk, interpret):
 
     dkv_kernel = functools.partial(_bwd_dkv_kernel, bq=bq, bk=bk, scale=scale,
                                    causal=causal, window=window)
+    q_spec = pl.BlockSpec((None, None, bq, D), lambda b, h, j, i: (b, h, i, 0))
+    kv_spec = pl.BlockSpec((None, None, bk, D), lambda b, h, j, i: (b, h, j, 0))
+    row_spec = pl.BlockSpec((None, None, 1, bq),
+                            lambda b, h, j, i: (b, h, 0, i))
     dk, dv = pl.pallas_call(
         dkv_kernel,
-        grid=(B, H, T // bk),
-        in_specs=[
-            pl.BlockSpec((None, None, S, D), lambda b, h, j: (b, h, 0, 0)),
-            pl.BlockSpec((None, None, bk, D), lambda b, h, j: (b, h, j, 0)),
-            pl.BlockSpec((None, None, bk, D), lambda b, h, j: (b, h, j, 0)),
-            pl.BlockSpec((None, None, S, D), lambda b, h, j: (b, h, 0, 0)),
-            pl.BlockSpec((None, None, S), lambda b, h, j: (b, h, 0)),
-            pl.BlockSpec((None, None, S), lambda b, h, j: (b, h, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((None, None, bk, D), lambda b, h, j: (b, h, j, 0)),
-            pl.BlockSpec((None, None, bk, D), lambda b, h, j: (b, h, j, 0)),
-        ],
+        grid=(B, H, T // bk, S // bq),
+        in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
+        out_specs=[kv_spec, kv_spec],
         out_shape=[
             jax.ShapeDtypeStruct((B, H, T, D), k.dtype),
             jax.ShapeDtypeStruct((B, H, T, D), v.dtype),
         ],
+        scratch_shapes=[pltpu.VMEM((bk, D), jnp.float32),
+                        pltpu.VMEM((bk, D), jnp.float32)],
         interpret=interpret,
     )(q, k, v, g, lse, delta)
     return dq, dk, dv
@@ -279,8 +309,8 @@ def _backward(q, k, v, o, lse, g, causal, window, bq, bk, interpret):
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def _flash(q, k, v, causal, window, bq, bk, interpret):
-    out, _ = _forward(q, k, v, causal, window, bq, bk, interpret)
-    return out
+    return _forward(q, k, v, causal, window, bq, bk, interpret,
+                    with_lse=False)
 
 
 def _flash_fwd(q, k, v, causal, window, bq, bk, interpret):

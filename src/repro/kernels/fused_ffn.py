@@ -4,8 +4,12 @@ y = (silu(x @ Wg) * (x @ Wu)) @ Wd, fused so the [N, F] hidden activations
 never round-trip HBM: the grid walks (row-block, F-block) with the F-block
 axis minor; each step computes a [br, bf] hidden tile and accumulates its
 contribution to the [br, D] output in VMEM scratch (emitted on the last
-F step).  VMEM per step ≈ br·D + 2·D·bf + bf·D + br·bf floats — sized so
-D ≤ 8k, bf = 512 fits comfortably in 128 MiB.
+F step).  Matmuls run in the operands' dtype with f32 accumulation.  Each
+call sizes Mosaic's scoped-VMEM limit from its own blocks (double-buffered
+x/weight/output tiles + f32 scratch): at d_model=2560, bf=512 the forward
+needs ~23 MiB, above the 16 MiB default.  The F block adapts to the hidden
+width (the largest 128-multiple <= 512 dividing it), so a d_ff sharded
+four ways (9728/4 = 2432) still tiles.
 
 The op carries a ``jax.custom_vjp`` whose backward *reuses the forward
 tiles*: nothing [N, F]-shaped is stashed as a residual — each backward
@@ -30,16 +34,55 @@ from jax.experimental.pallas import tpu as pltpu
 
 DEFAULT_BR = 256
 DEFAULT_BF = 512
+# v5e has 128 MiB of VMEM per core but Mosaic's default scoped limit is
+# 16 MiB; a d_model=2560 tile set needs more, so every call states its need
+VMEM_CAP = 100 * 2 ** 20
+
+
+def pick_block(n: int, target: int, align: int = 128) -> int:
+    """Largest block <= ``target`` that tiles ``n`` exactly: ``n`` itself
+    when it fits, ``target`` when it divides, else the largest multiple of
+    ``align`` dividing ``n`` (None when there is none — the caller falls
+    back to the jnp path)."""
+    if n <= target:
+        return n
+    if n % target == 0:
+        return target
+    b = target - target % align
+    while b >= align and n % b:
+        b -= align
+    return b if b >= align else None
+
+
+def blocks_ok(n_rows: int, d_ff: int) -> bool:
+    """Whether the kernel's grid can tile an [n_rows, D] x [D, d_ff] call."""
+    return (pick_block(n_rows, DEFAULT_BR, 8) is not None
+            and pick_block(d_ff, DEFAULT_BF) is not None)
+
+
+def _params(block_bytes: int, scratch_bytes: int, tile_bytes: int):
+    """Mosaic params sized from the call's blocks: every pipelined block is
+    double-buffered, plus scratch accumulators and the f32 hidden tiles."""
+    need = 2 * block_bytes + scratch_bytes + 8 * tile_bytes + (4 << 20)
+    return pltpu.CompilerParams(
+        vmem_limit_bytes=int(min(max(need, 16 << 20), VMEM_CAP)))
 
 
 def _hidden_tile(x, wg_ref, wu_ref):
     """Recompute one [br, bf] forward tile: returns (g, sg, u) f32 where
     ``sg = logistic(g)`` so callers get silu(g) = g*sg and its derivative."""
-    g = jax.lax.dot_general(x, wg_ref[...].astype(jnp.float32),
-                            (((1,), (0,)), ((), ())))
-    u = jax.lax.dot_general(x, wu_ref[...].astype(jnp.float32),
-                            (((1,), (0,)), ((), ())))
+    g = _dot(x, wg_ref[...], ((1,), (0,)))
+    u = _dot(x, wu_ref[...], ((1,), (0,)))
     return g, jax.lax.logistic(g), u
+
+
+def _dot(a, b, contract):
+    """MXU matmul in the operands' dtype with f32 accumulation (an f32
+    tile meeting a narrower weight tile is cast down to it first)."""
+    dt = b.dtype if jnp.dtype(b.dtype).itemsize < 4 else a.dtype
+    return jax.lax.dot_general(a.astype(dt), b.astype(dt),
+                               (contract, ((), ())),
+                               preferred_element_type=jnp.float32)
 
 
 # ---------------------------------------------------------------------------
@@ -57,20 +100,27 @@ def _ffn_kernel(x_ref, wg_ref, wu_ref, wd_ref, y_ref, acc_ref):
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    x = x_ref[...].astype(jnp.float32)
-    g, sg, u = _hidden_tile(x, wg_ref, wu_ref)
+    g, sg, u = _hidden_tile(x_ref[...], wg_ref, wu_ref)
     h = (g * sg) * u                                     # silu(g) * u
-    acc_ref[...] += jax.lax.dot_general(
-        h, wd_ref[...].astype(jnp.float32), (((1,), (0,)), ((), ())))
+    acc_ref[...] += _dot(h, wd_ref[...], ((1,), (0,)))
 
     @pl.when(j == nf - 1)
     def _emit():
         y_ref[...] = acc_ref[...].astype(y_ref.dtype)
 
 
+def _nbytes(shape, dtype) -> int:
+    n = jnp.dtype(dtype).itemsize
+    for d in shape:
+        n *= d
+    return n
+
+
 def _forward(x, w_gate, w_up, w_down, br, bf, interpret):
     N, D = x.shape
     F = w_gate.shape[1]
+    blocks = (2 * _nbytes((br, D), x.dtype)
+              + 3 * _nbytes((D, bf), w_gate.dtype))
     return pl.pallas_call(
         _ffn_kernel,
         grid=(N // br, F // bf),
@@ -83,6 +133,8 @@ def _forward(x, w_gate, w_up, w_down, br, bf, interpret):
         out_specs=pl.BlockSpec((br, D), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((N, D), x.dtype),
         scratch_shapes=[pltpu.VMEM((br, D), jnp.float32)],
+        compiler_params=_params(blocks, _nbytes((br, D), jnp.float32),
+                                _nbytes((br, bf), jnp.float32)),
         interpret=interpret,
     )(x, w_gate, w_up, w_down)
 
@@ -98,8 +150,7 @@ def _bwd_hidden_grads(x, dy, wg_ref, wu_ref, wd_ref):
     g, sg, u = _hidden_tile(x, wg_ref, wu_ref)
     silu = g * sg
     h = silu * u
-    dh = jax.lax.dot_general(dy, wd_ref[...].astype(jnp.float32),
-                             (((1,), (1,)), ((), ())))    # [br,bf]
+    dh = _dot(dy, wd_ref[...], ((1,), (1,)))              # [br,bf]
     du = dh * silu
     dg = dh * u * (sg + g * sg * (1.0 - sg))              # d silu / dg
     return h, dg, du
@@ -114,14 +165,10 @@ def _bwd_dx_kernel(x_ref, wg_ref, wu_ref, wd_ref, dy_ref, dx_ref, acc_ref):
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    x = x_ref[...].astype(jnp.float32)
-    dy = dy_ref[...].astype(jnp.float32)
-    _, dg, du = _bwd_hidden_grads(x, dy, wg_ref, wu_ref, wd_ref)
-    acc_ref[...] += (
-        jax.lax.dot_general(dg, wg_ref[...].astype(jnp.float32),
-                            (((1,), (1,)), ((), ())))
-        + jax.lax.dot_general(du, wu_ref[...].astype(jnp.float32),
-                              (((1,), (1,)), ((), ()))))
+    _, dg, du = _bwd_hidden_grads(x_ref[...], dy_ref[...],
+                                  wg_ref, wu_ref, wd_ref)
+    acc_ref[...] += (_dot(dg, wg_ref[...], ((1,), (1,)))
+                     + _dot(du, wu_ref[...], ((1,), (1,))))
 
     @pl.when(j == nf - 1)
     def _emit():
@@ -142,12 +189,12 @@ def _bwd_dw_kernel(x_ref, wg_ref, wu_ref, wd_ref, dy_ref,
         dwu_acc[...] = jnp.zeros_like(dwu_acc)
         dwd_acc[...] = jnp.zeros_like(dwd_acc)
 
-    x = x_ref[...].astype(jnp.float32)
-    dy = dy_ref[...].astype(jnp.float32)
+    x = x_ref[...]
+    dy = dy_ref[...]
     h, dg, du = _bwd_hidden_grads(x, dy, wg_ref, wu_ref, wd_ref)
-    dwg_acc[...] += jax.lax.dot_general(x, dg, (((0,), (0,)), ((), ())))
-    dwu_acc[...] += jax.lax.dot_general(x, du, (((0,), (0,)), ((), ())))
-    dwd_acc[...] += jax.lax.dot_general(h, dy, (((0,), (0,)), ((), ())))
+    dwg_acc[...] += _dot(x, dg.astype(x.dtype), ((0,), (0,)))
+    dwu_acc[...] += _dot(x, du.astype(x.dtype), ((0,), (0,)))
+    dwd_acc[...] += _dot(h.astype(dy.dtype), dy, ((0,), (0,)))
 
     @pl.when(i == nr - 1)
     def _emit():
@@ -159,6 +206,9 @@ def _bwd_dw_kernel(x_ref, wg_ref, wu_ref, wd_ref, dy_ref,
 def _backward(x, w_gate, w_up, w_down, dy, br, bf, interpret):
     N, D = x.shape
     F = w_gate.shape[1]
+    row = _nbytes((br, D), x.dtype)
+    wblk = _nbytes((D, bf), w_gate.dtype)
+    tile = _nbytes((br, bf), jnp.float32)
 
     dx = pl.pallas_call(
         _bwd_dx_kernel,
@@ -173,6 +223,8 @@ def _backward(x, w_gate, w_up, w_down, dy, br, bf, interpret):
         out_specs=pl.BlockSpec((br, D), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((N, D), x.dtype),
         scratch_shapes=[pltpu.VMEM((br, D), jnp.float32)],
+        compiler_params=_params(3 * row + 3 * wblk,
+                                _nbytes((br, D), jnp.float32), tile),
         interpret=interpret,
     )(x, w_gate, w_up, w_down, dy)
 
@@ -199,6 +251,8 @@ def _backward(x, w_gate, w_up, w_down, dy, br, bf, interpret):
         scratch_shapes=[pltpu.VMEM((D, bf), jnp.float32),
                         pltpu.VMEM((D, bf), jnp.float32),
                         pltpu.VMEM((bf, D), jnp.float32)],
+        compiler_params=_params(2 * row + 6 * wblk,
+                                3 * _nbytes((D, bf), jnp.float32), tile),
         interpret=interpret,
     )(x, w_gate, w_up, w_down, dy)
     return dx, dwg, dwu, dwd
@@ -235,7 +289,7 @@ def swiglu_ffn(x: jax.Array, w_gate: jax.Array, w_up: jax.Array,
     (``jax.custom_vjp``: backward recomputes the forward tiles)."""
     N, D = x.shape
     F = w_gate.shape[1]
-    br = min(br, N)
-    bf = min(bf, F)
-    assert N % br == 0 and F % bf == 0, (N, br, F, bf)
+    br = pick_block(N, br, 8)
+    bf = pick_block(F, bf)
+    assert br is not None and bf is not None, (N, F)
     return _swiglu(x, w_gate, w_up, w_down, br, bf, interpret)

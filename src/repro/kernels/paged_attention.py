@@ -4,13 +4,15 @@ The dense flash-decode kernel (decode_attention.py) streams a contiguous
 [T]-long KV cache; this kernel streams a *paged* one: K/V live in a pooled
 ``[num_blocks, block_size, KV, Dh]`` tensor shared by every sequence, and
 each query row follows its int32 block table ``[B, max_blocks]`` through the
-pool.  The grid is (batch, kv-head, table-column) with the table column as
-the *minor* axis, so TPU executes one pool block per step per (b, h) and the
+pool.  The grid is (batch, table-column) with the table column as the
+*minor* axis, so TPU executes one pool block per step per row and the
 online-softmax state (m, l, acc) lives in VMEM scratch across those steps —
-exactly the dense kernel's structure, with the block index indirected
-through a scalar-prefetched table (``pltpu.PrefetchScalarGridSpec``: the
-table is resident before the kernel body runs, so the DMA for step j can be
-issued from ``table[b, j]``).
+exactly the dense kernel's structure (and its shared per-head step), with
+the block index indirected through a scalar-prefetched table
+(``pltpu.PrefetchScalarGridSpec``: the table is resident before the kernel
+body runs, so the DMA for step j can be issued from ``table[b, j]``).  Each
+step's tile is the whole ``(bs, KV, D)`` pool block — one contiguous DMA —
+and the kernel loops the kv-heads inside.
 
 Masking is purely positional, which subsumes every tail case: ``pos_pool``
 carries each pool entry's absolute position (-1 = never written), so the
@@ -24,10 +26,11 @@ registry's ``supports_paged_decode`` excludes them).
 
 The quantized variant (:func:`paged_decode_attention_q8`) streams int8
 pools plus per-(block, kv-head) f32 scales ``[N, KV]`` and dequantizes
-each tile *in-loop* in VMEM — the scale rides the same block-table
+each tile *in-loop* in VMEM — the scale row rides the same block-table
 indirection as the K/V tiles, so full-precision KV never exists in HBM;
 it is reconstructed one [bs, D] tile at a time inside the online-softmax
-loop.
+loop.  Entry positions and scale rows are viewed as ``[N, 1, bs]`` /
+``[N, 1, KV]`` so each step's tile spans the array's full trailing dims.
 """
 from __future__ import annotations
 
@@ -38,73 +41,83 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-NEG_INF = -1e30
+from repro.kernels import decode_attention as _da
 
 
-def _online_update(q, kb, vb, kv_pos, pos, o_ref, m_ref, l_ref, acc_ref,
-                   j, nb):
-    """One online-softmax step over a [bs, D] tile: init scratch at j == 0,
-    fold the tile into (m, l, acc), emit at j == nb - 1.  Shared by the f32
-    and int8 kernels — they differ only in how the tile is materialized."""
+def _paged_step(b, j, pos_ref, q_ref, k_ref, v_ref, kvp_ref, o_ref,
+                m_ref, l_ref, acc_ref, scale, k_scale=None, v_scale=None):
+    """One (row, table-column) step: init at j == 0, fold the pool block
+    in, emit at the last column.  Shared by the f32 and int8 kernels."""
     @pl.when(j == 0)
     def _init():
-        m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+        _da.init_scratch(m_ref, l_ref, acc_ref)
 
-    s = jax.lax.dot_general(q, kb, (((1,), (1,)), ((), ())))  # [G,bs]
-    valid = (kv_pos >= 0) & (kv_pos <= pos)
-    s = jnp.where(valid[None, :], s, NEG_INF)
+    kv_pos = kvp_ref[...]                                        # [1,bs]
+    valid = (kv_pos >= 0) & (kv_pos <= pos_ref[b])
+    _da.online_softmax_step(q_ref, k_ref, v_ref, valid, m_ref, l_ref,
+                            acc_ref, scale=scale, k_scale=k_scale,
+                            v_scale=v_scale)
 
-    m_prev, l_prev, acc_prev = m_ref[...], l_ref[...], acc_ref[...]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-    p = jnp.exp(s - m_new[:, None])
-    corr = jnp.exp(m_prev - m_new)
-    m_ref[...] = m_new
-    l_ref[...] = l_prev * corr + jnp.sum(p, axis=1)
-    acc_ref[...] = acc_prev * corr[:, None] + jax.lax.dot_general(
-        p, vb, (((1,), (0,)), ((), ())))
-
-    @pl.when(j == nb - 1)
+    @pl.when(j == pl.num_programs(1) - 1)
     def _emit():
-        o_ref[...] = (acc_ref[...] /
-                      jnp.maximum(l_ref[...], 1e-30)[:, None]
-                      ).astype(o_ref.dtype)
+        _da.emit(o_ref, l_ref, acc_ref)
 
 
 def _paged_kernel(tbl_ref, pos_ref, q_ref, k_ref, v_ref, kvp_ref, o_ref,
                   m_ref, l_ref, acc_ref, *, scale: float):
-    """Grid (B, KV, M).  q_ref [G,D]; k_ref/v_ref [bs,D] (the pool block the
-    table's (b, j) entry selects); kvp_ref [bs]; tbl_ref/pos_ref are
-    scalar-prefetched; scratch m/l [G], acc [G,D]."""
-    b = pl.program_id(0)
-    j = pl.program_id(2)
-    nb = pl.num_programs(2)
-
-    q = q_ref[...].astype(jnp.float32) * scale          # [G,D]
-    kb = k_ref[...].astype(jnp.float32)                 # [bs,D]
-    vb = v_ref[...].astype(jnp.float32)
-    kv_pos = kvp_ref[...]                               # [bs]
-    _online_update(q, kb, vb, kv_pos, pos_ref[b],
-                   o_ref, m_ref, l_ref, acc_ref, j, nb)
+    """Grid (B, M).  q_ref [KV,G,D]; k_ref/v_ref [bs,KV,D] (the pool block
+    the table's (b, j) entry selects); kvp_ref [1,bs]; tbl_ref/pos_ref are
+    scalar-prefetched; scratch m/l [KV,G,1], acc [KV,G,D]."""
+    _paged_step(pl.program_id(0), pl.program_id(1), pos_ref, q_ref, k_ref,
+                v_ref, kvp_ref, o_ref, m_ref, l_ref, acc_ref, scale)
 
 
 def _paged_q8_kernel(tbl_ref, pos_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
                      kvp_ref, o_ref, m_ref, l_ref, acc_ref, *, scale: float):
-    """int8 variant: k_ref/v_ref are int8 [bs,D] tiles and ks_ref/vs_ref
-    the block's per-(block, kv-head) f32 scale (a [1] tile); dequant
-    happens here, in VMEM, inside the loop — HBM only ever holds the
-    quantized pool."""
-    b = pl.program_id(0)
-    j = pl.program_id(2)
-    nb = pl.num_programs(2)
+    """int8 variant: k_ref/v_ref are int8 [bs,KV,D] tiles and ks_ref/vs_ref
+    the block's per-kv-head f32 scale row [1,KV]; dequant happens here, in
+    VMEM, inside the loop — HBM only ever holds the quantized pool."""
+    _paged_step(pl.program_id(0), pl.program_id(1), pos_ref, q_ref, k_ref,
+                v_ref, kvp_ref, o_ref, m_ref, l_ref, acc_ref, scale,
+                k_scale=ks_ref[...], v_scale=vs_ref[...])
 
-    q = q_ref[...].astype(jnp.float32) * scale                    # [G,D]
-    kb = k_ref[...].astype(jnp.float32) * ks_ref[0]               # [bs,D]
-    vb = v_ref[...].astype(jnp.float32) * vs_ref[0]
-    kv_pos = kvp_ref[...]                                         # [bs]
-    _online_update(q, kb, vb, kv_pos, pos_ref[b],
-                   o_ref, m_ref, l_ref, acc_ref, j, nb)
+
+def _paged_call(kernel, q, pools, extra_rows, pos_pool, block_table, pos,
+                interpret):
+    """Shared pallas_call plumbing: ``pools`` are the [N,bs,KV,D] K/V pools,
+    ``extra_rows`` per-block rows (scales) viewed as [N,1,n]."""
+    B, H, D = q.shape
+    N, bs, KV = pools[0].shape[:3]
+    M = block_table.shape[1]
+    G = H // KV
+
+    def blk(i, j, tbl, pos):
+        return (tbl[i, j], 0, 0)
+
+    pool_spec = pl.BlockSpec((None, bs, KV, D),
+                             lambda i, j, tbl, pos: (tbl[i, j], 0, 0, 0))
+    row_specs = [pl.BlockSpec((None, 1, r.shape[-1]), blk)
+                 for r in extra_rows]
+    head_spec = pl.BlockSpec((None, KV, G, D),
+                             lambda i, j, tbl, pos: (i, 0, 0, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,           # block_table, pos
+        grid=(B, M),
+        in_specs=[head_spec, pool_spec, pool_spec, *row_specs,
+                  pl.BlockSpec((None, 1, bs), blk)],
+        out_specs=head_spec,
+        scratch_shapes=_da.scratch_shapes(KV, G, D),
+    )
+    out = pl.pallas_call(
+        functools.partial(kernel, scale=D ** -0.5),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, KV, G, D), q.dtype),
+        interpret=interpret,
+    )(block_table.astype(jnp.int32), pos.astype(jnp.int32),
+      q.reshape(B, KV, G, D), *pools,
+      *(r.reshape(N, 1, r.shape[-1]) for r in extra_rows),
+      pos_pool.reshape(N, 1, bs))
+    return out.reshape(B, H, D)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -115,42 +128,8 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
     """q [B,H,D]; k_pool/v_pool [N,bs,KV,D] (grouped heads);
     pos_pool [N,bs] int32 (-1 = empty); block_table [B,M] int32;
     pos [B] int32 -> [B,H,D]."""
-    B, H, D = q.shape
-    bs, KV = k_pool.shape[1], k_pool.shape[2]
-    M = block_table.shape[1]
-    G = H // KV
-    scale = D ** -0.5
-
-    qg = q.reshape(B, KV, G, D)
-    kernel = functools.partial(_paged_kernel, scale=scale)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,           # block_table, pos
-        grid=(B, KV, M),
-        in_specs=[
-            pl.BlockSpec((None, None, G, D),
-                         lambda b, h, j, tbl, pos: (b, h, 0, 0)),
-            pl.BlockSpec((None, bs, None, D),
-                         lambda b, h, j, tbl, pos: (tbl[b, j], 0, h, 0)),
-            pl.BlockSpec((None, bs, None, D),
-                         lambda b, h, j, tbl, pos: (tbl[b, j], 0, h, 0)),
-            pl.BlockSpec((None, bs),
-                         lambda b, h, j, tbl, pos: (tbl[b, j], 0)),
-        ],
-        out_specs=pl.BlockSpec((None, None, G, D),
-                               lambda b, h, j, tbl, pos: (b, h, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((G,), jnp.float32),
-            pltpu.VMEM((G,), jnp.float32),
-            pltpu.VMEM((G, D), jnp.float32),
-        ],
-    )
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, KV, G, D), q.dtype),
-        interpret=interpret,
-    )(block_table, pos, qg, k_pool, v_pool, pos_pool)
-    return out.reshape(B, H, D)
+    return _paged_call(_paged_kernel, q, (k_pool, v_pool), (), pos_pool,
+                       block_table, pos, interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -164,43 +143,6 @@ def paged_decode_attention_q8(q: jax.Array, k_pool: jax.Array,
     pos_pool [N,bs] int32 (-1 = empty); block_table [B,M] int32; pos [B]
     int32 -> [B,H,D].  The scales ride the same block-table indirection
     as the K/V tiles and dequant happens in-loop in VMEM."""
-    B, H, D = q.shape
-    bs, KV = k_pool.shape[1], k_pool.shape[2]
-    M = block_table.shape[1]
-    G = H // KV
-    scale = D ** -0.5
-
-    qg = q.reshape(B, KV, G, D)
-    kernel = functools.partial(_paged_q8_kernel, scale=scale)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,           # block_table, pos
-        grid=(B, KV, M),
-        in_specs=[
-            pl.BlockSpec((None, None, G, D),
-                         lambda b, h, j, tbl, pos: (b, h, 0, 0)),
-            pl.BlockSpec((None, bs, None, D),
-                         lambda b, h, j, tbl, pos: (tbl[b, j], 0, h, 0)),
-            pl.BlockSpec((None, bs, None, D),
-                         lambda b, h, j, tbl, pos: (tbl[b, j], 0, h, 0)),
-            pl.BlockSpec((None, 1),
-                         lambda b, h, j, tbl, pos: (tbl[b, j], h)),
-            pl.BlockSpec((None, 1),
-                         lambda b, h, j, tbl, pos: (tbl[b, j], h)),
-            pl.BlockSpec((None, bs),
-                         lambda b, h, j, tbl, pos: (tbl[b, j], 0)),
-        ],
-        out_specs=pl.BlockSpec((None, None, G, D),
-                               lambda b, h, j, tbl, pos: (b, h, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((G,), jnp.float32),
-            pltpu.VMEM((G,), jnp.float32),
-            pltpu.VMEM((G, D), jnp.float32),
-        ],
-    )
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, KV, G, D), q.dtype),
-        interpret=interpret,
-    )(block_table, pos, qg, k_pool, v_pool, k_scale, v_scale, pos_pool)
-    return out.reshape(B, H, D)
+    return _paged_call(_paged_q8_kernel, q, (k_pool, v_pool),
+                       (k_scale, v_scale), pos_pool, block_table, pos,
+                       interpret)

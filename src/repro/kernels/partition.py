@@ -54,7 +54,6 @@ from typing import Any, Optional
 import jax
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.kernels import decode_attention as _da
 from repro.kernels import flash_attention as _fa
 from repro.kernels import fused_ffn as _ffn
@@ -179,10 +178,10 @@ def _flash_fwd_sharded(q, k, v, causal, window, part: KernelPartition):
     T = k.shape[2]
     bq, bk = min(_fa.DEFAULT_BQ, S), min(_fa.DEFAULT_BK, T)
     spec = P(part.batch_spec, part.model, None, None)
-    lse_spec = P(part.batch_spec, part.model, None)
+    lse_spec = P(part.batch_spec, part.model, None, None)
     body = lambda q, k, v: _fa._forward(q, k, v, causal, window, bq, bk,
                                         _interpret())
-    out, lse = shard_map(
+    out, lse = jax.shard_map(
         body, mesh=part.mesh, in_specs=(spec, spec, spec),
         out_specs=(spec, lse_spec), check_vma=False)(q, k, v)
     return out, (q, k, v, out, lse)
@@ -194,12 +193,12 @@ def _flash_bwd_sharded(causal, window, part: KernelPartition, res, g):
     T = k.shape[2]
     bq, bk = min(_fa.DEFAULT_BQ, S), min(_fa.DEFAULT_BK, T)
     spec = P(part.batch_spec, part.model, None, None)
-    lse_spec = P(part.batch_spec, part.model, None)
+    lse_spec = P(part.batch_spec, part.model, None, None)
     body = lambda q, k, v, o, lse, g: _fa._backward(
         q, k, v, o, lse, g, causal, window, bq, bk, _interpret())
     # every operand is head-sharded, so dq/dk/dv are shard-local: the psum
     # for the GQA repeat / projection weights happens outside with autodiff
-    return shard_map(
+    return jax.shard_map(
         body, mesh=part.mesh,
         in_specs=(spec, spec, spec, spec, lse_spec, spec),
         out_specs=(spec, spec, spec), check_vma=False)(q, k, v, out, lse, g)
@@ -245,23 +244,18 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
 # ---------------------------------------------------------------------------
 
 
-def _ffn_blocks_ok(n_loc: int, f_loc: int) -> bool:
-    """Per-shard analog of models.mlp.fused_ffn_supported's grid gate."""
-    return ((n_loc <= _ffn.DEFAULT_BR or n_loc % _ffn.DEFAULT_BR == 0)
-            and (f_loc <= _ffn.DEFAULT_BF or f_loc % _ffn.DEFAULT_BF == 0))
-
-
 def _swiglu_fwd_sharded(x, wg, wu, wd, part: KernelPartition):
     N, D = x.shape
     F = wg.shape[1]
     n_loc, f_loc = N // part.dp(), F // part.tp()
-    br, bf = min(_ffn.DEFAULT_BR, n_loc), min(_ffn.DEFAULT_BF, f_loc)
+    br = _ffn.pick_block(n_loc, _ffn.DEFAULT_BR, 8)
+    bf = _ffn.pick_block(f_loc, _ffn.DEFAULT_BF)
 
     def body(x, wg, wu, wd):
         y = _ffn._forward(x, wg, wu, wd, br, bf, _interpret())
         return jax.lax.psum(y, part.model)     # row-parallel partial outputs
 
-    y = shard_map(
+    y = jax.shard_map(
         body, mesh=part.mesh,
         in_specs=(P(part.batch_spec, None), P(None, part.model),
                   P(None, part.model), P(part.model, None)),
@@ -274,7 +268,8 @@ def _swiglu_bwd_sharded(part: KernelPartition, res, dy):
     N, D = x.shape
     F = wg.shape[1]
     n_loc, f_loc = N // part.dp(), F // part.tp()
-    br, bf = min(_ffn.DEFAULT_BR, n_loc), min(_ffn.DEFAULT_BF, f_loc)
+    br = _ffn.pick_block(n_loc, _ffn.DEFAULT_BR, 8)
+    bf = _ffn.pick_block(f_loc, _ffn.DEFAULT_BF)
 
     def body(x, wg, wu, wd, dy):
         dx, dwg, dwu, dwd = _ffn._backward(x, wg, wu, wd, dy, br, bf,
@@ -285,7 +280,7 @@ def _swiglu_bwd_sharded(part: KernelPartition, res, dy):
                              for t in (dwg, dwu, dwd))
         return dx, dwg, dwu, dwd
 
-    return shard_map(
+    return jax.shard_map(
         body, mesh=part.mesh,
         in_specs=(P(part.batch_spec, None), P(None, part.model),
                   P(None, part.model), P(part.model, None),
@@ -316,7 +311,7 @@ def swiglu_ffn(x: jax.Array, w_gate: jax.Array, w_up: jax.Array,
         if model is not None:
             part = KernelPartition(mesh, model,
                                    _batch_axes(rules, mesh, x.shape[0]))
-            if _ffn_blocks_ok(x.shape[0] // part.dp(), F // part.tp()):
+            if _ffn.blocks_ok(x.shape[0] // part.dp(), F // part.tp()):
                 return _swiglu_sharded(x, w_gate, w_up, w_down, part)
     return ops.swiglu_ffn(x, w_gate, w_up, w_down)
 
@@ -365,7 +360,7 @@ def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                 return _gather_heads(out, part)
 
             b, m = part.batch_spec, part.model
-            return shard_map(
+            return jax.shard_map(
                 body, mesh=part.mesh,
                 in_specs=(P(b, m, None), P(b, None, m, None),
                           P(b, None, m, None), P(b, None), P(b)),
@@ -393,7 +388,7 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
                 return _gather_heads(out, part)
 
             b, m = part.batch_spec, part.model
-            return shard_map(
+            return jax.shard_map(
                 body, mesh=part.mesh,
                 in_specs=(P(b, m, None), P(None, None, m, None),
                           P(None, None, m, None), P(None, None),
@@ -427,7 +422,7 @@ def paged_decode_attention_q8(q: jax.Array, k_pool: jax.Array,
                 return _gather_heads(out, part)
 
             b, m = part.batch_spec, part.model
-            return shard_map(
+            return jax.shard_map(
                 body, mesh=part.mesh,
                 in_specs=(P(b, m, None), P(None, None, m, None),
                           P(None, None, m, None), P(None, m),

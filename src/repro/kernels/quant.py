@@ -38,15 +38,15 @@ def _resolve_interpret(interpret):
 
 def _quant_kernel(x_ref, q_ref, s_ref):
     x = x_ref[...].astype(jnp.float32)               # [ROWS, BLOCK]
-    scale = jnp.max(jnp.abs(x), axis=1) / 127.0      # [ROWS]
+    scale = jnp.max(jnp.abs(x), axis=1, keepdims=True) / 127.0  # [ROWS, 1]
     safe = jnp.where(scale > 0, scale, 1.0)
-    q = jnp.clip(jnp.round(x / safe[:, None]), -127, 127)
+    q = jnp.clip(jnp.round(x / safe), -127, 127)
     q_ref[...] = q.astype(jnp.int8)
     s_ref[...] = scale
 
 
 def _dequant_kernel(q_ref, s_ref, x_ref):
-    x_ref[...] = q_ref[...].astype(jnp.float32) * s_ref[...][:, None]
+    x_ref[...] = q_ref[...].astype(jnp.float32) * s_ref[...]
 
 
 def block_quant(x: jax.Array):
@@ -79,12 +79,12 @@ def _quantize_int8(x, *, block, rows, interpret):
         grid=(nb // rows,),
         in_specs=[pl.BlockSpec((rows, block), lambda i: (i, 0))],
         out_specs=[pl.BlockSpec((rows, block), lambda i: (i, 0)),
-                   pl.BlockSpec((rows,), lambda i: (i,))],
+                   pl.BlockSpec((rows, 1), lambda i: (i, 0))],
         out_shape=[jax.ShapeDtypeStruct((nb, block), jnp.int8),
-                   jax.ShapeDtypeStruct((nb,), jnp.float32)],
+                   jax.ShapeDtypeStruct((nb, 1), jnp.float32)],
         interpret=interpret,
     )(x)
-    return q, s
+    return q, s[:, 0]
 
 
 def quantize_int8(x: jax.Array, *, block: int = BLOCK, rows: int = ROWS,
@@ -107,11 +107,11 @@ def _dequantize_int8(q, scale, *, rows, interpret):
         _dequant_kernel,
         grid=(nb // rows,),
         in_specs=[pl.BlockSpec((rows, block), lambda i: (i, 0)),
-                  pl.BlockSpec((rows,), lambda i: (i,))],
+                  pl.BlockSpec((rows, 1), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((rows, block), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((nb, block), jnp.float32),
         interpret=interpret,
-    )(q, scale)
+    )(q, scale[:, None])
 
 
 def dequantize_int8(q: jax.Array, scale: jax.Array, *, rows: int = ROWS,

@@ -12,6 +12,15 @@ chip-to-chip LVDS mesh vs the 10 Gbps SFP+ links between MCMs.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape, names):
+    """``jax.make_mesh`` with every axis ``Auto``: the model code shards
+    through bare-``PartitionSpec`` constraints, which JAX (>= 0.7) only
+    accepts on Auto axes (``make_mesh`` now defaults to Explicit)."""
+    return jax.make_mesh(tuple(shape), tuple(names),
+                         axis_types=(AxisType.Auto,) * len(shape))
 
 
 def mesh_from_spec(spec: str):
@@ -35,20 +44,20 @@ def mesh_from_spec(spec: str):
                          "(e.g. '8', '2x4', '2x2x2')")
     if any(d <= 0 for d in dims):
         raise ValueError(f"mesh spec {spec!r}: every dim must be positive")
-    return jax.make_mesh(dims, names[len(dims)])
+    return make_mesh(dims, names[len(dims)])
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_smoke_mesh(*, multi_pod: bool = False):
     """8-device mesh for CPU integration tests (2x2x2 or 2x4)."""
     shape = (2, 2, 2) if multi_pod else (2, 4)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def mesh_axes(mesh) -> dict:

@@ -25,21 +25,62 @@ Data-integrity knobs: ``--burn-in`` runs the full qualification gate
 before serving, and ``--scrub-every N`` arms the engine's corruption
 scrub — with ``--fault-plan 'tick=6,kind=corrupt,target=kv,seed=7'`` the
 whole detect -> quarantine -> replay path runs live.
+
+``--bf16-params`` materializes the weights in bf16 (a 4 B-parameter model
+is 16 GB in f32, 8 GB in bf16).  ``build_runtime`` and ``serve`` are the
+launcher's two steps as functions — ``chip_smoke.py`` drives them.
 """
 from __future__ import annotations
 
 import argparse
 
+import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config, get_smoke_config
 from repro.ft.inject import FaultInjector
 from repro.launch import preflight as pf
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import mesh_from_spec
 from repro.obs.export import dump_metrics, write_events_jsonl
 from repro.obs.metrics import percentile
 from repro.runtime import Runtime
-from repro.serve.engine import Request
+from repro.serve.engine import Request, ServeEngine
+
+
+def build_runtime(arch: str, *, smoke: bool = False, mesh: str = "",
+                  capacity: int = 128, kv_layout: str = "dense",
+                  kv_dtype: str = "f32", scheduler: bool = False,
+                  token_budget: int = 0, chunk_size: int = 0,
+                  bf16_params: bool = False, params=None,
+                  seed: int = 0) -> Runtime:
+    """The decode-shaped Runtime the launcher serves from (``mesh`` is a
+    spec string, "" = single device; ``params`` reuses weights)."""
+    cfg = get_smoke_config(arch) if smoke else get_config(arch)
+    sched_kw = {}
+    if token_budget:
+        sched_kw["token_budget"] = token_budget
+    if chunk_size:
+        sched_kw["chunk_size"] = chunk_size
+    return Runtime.create(cfg, mesh_from_spec(mesh) if mesh else None,
+                          shape_kind="decode", capacity=capacity,
+                          kv_layout=kv_layout, kv_dtype=kv_dtype,
+                          scheduler=scheduler, sched_kw=sched_kw or None,
+                          param_dtype=(jnp.bfloat16 if bf16_params
+                                       else jnp.float32),
+                          seed=seed, params=params)
+
+
+def serve(rt: Runtime, requests, *, slots: int = 4,
+          **engine_kw) -> ServeEngine:
+    """Build ``rt``'s engine, submit ``requests`` and run them to
+    completion; returns the engine (``finished``, ``stats``,
+    ``ft_events``, ``latency_summary()``)."""
+    eng = rt.engine(num_slots=slots, **engine_kw)
+    for r in requests:
+        eng.submit(r)
+    eng.run_to_completion()
+    return eng
 
 
 def main(argv=None):
@@ -52,6 +93,8 @@ def main(argv=None):
     ap.add_argument("--slots", type=int, default=4)
     ap.add_argument("--capacity", type=int, default=128)
     ap.add_argument("--mesh", default="")
+    ap.add_argument("--bf16-params", action="store_true",
+                    help="materialize the weights in bf16 instead of f32")
     ap.add_argument("--kv-layout", default="dense",
                     choices=("dense", "paged"),
                     help="serve KV layout: dense per-slot slabs or the "
@@ -99,19 +142,14 @@ def main(argv=None):
                          "file at exit (chrome://tracing / Perfetto)")
     args = ap.parse_args(argv)
 
-    cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
-    mesh = mesh_from_spec(args.mesh) if args.mesh else None
-    sched_kw = {}
-    if args.token_budget:
-        sched_kw["token_budget"] = args.token_budget
-    if args.chunk_size:
-        sched_kw["chunk_size"] = args.chunk_size
-    rt = Runtime.create(cfg, mesh, shape_kind="decode",
-                        capacity=args.capacity,
-                        kv_layout=args.kv_layout,
-                        kv_dtype=args.kv_dtype,
-                        scheduler=args.scheduler,
-                        sched_kw=sched_kw or None)
+    enable_compile_cache()
+    rt = build_runtime(args.arch, smoke=args.smoke, mesh=args.mesh,
+                       capacity=args.capacity, kv_layout=args.kv_layout,
+                       kv_dtype=args.kv_dtype, scheduler=args.scheduler,
+                       token_budget=args.token_budget,
+                       chunk_size=args.chunk_size,
+                       bf16_params=args.bf16_params)
+    cfg, mesh = rt.cfg, rt.mesh
     if args.trace_out:
         rt.telemetry().tracer.enable()
 
@@ -136,16 +174,15 @@ def main(argv=None):
                  scrub_every=args.scrub_every)
     if args.fault_plan:
         ft_kw["injector"] = FaultInjector.parse(args.fault_plan)
-    eng = rt.engine(num_slots=args.slots, **ft_kw)
     rng = np.random.default_rng(0)
-    for i in range(args.requests):
-        eng.submit(Request(
-            rid=i,
-            prompt=rng.integers(0, cfg.vocab_size, size=args.prompt_len,
-                                dtype=np.int32),
-            max_new_tokens=args.max_new))
-    stats = eng.run_to_completion()
-    print("engine:", stats.summary)
+    requests = [Request(rid=i,
+                        prompt=rng.integers(0, cfg.vocab_size,
+                                            size=args.prompt_len,
+                                            dtype=np.int32),
+                        max_new_tokens=args.max_new)
+                for i in range(args.requests)]
+    eng = serve(rt, requests, slots=args.slots, **ft_kw)
+    print("engine:", eng.stats.summary)
     if eng.ft_events:
         n = write_events_jsonl(eng.ft_events, args.events_out)
         if args.events_out not in ("", "-"):

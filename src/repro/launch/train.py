@@ -24,6 +24,7 @@ from repro.configs import get_config, get_smoke_config
 from repro.data.pipeline import DataConfig, synthetic_batch
 from repro.ft.straggler import StragglerMonitor
 from repro.launch import preflight as pf
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import mesh_from_spec
 from repro.optim.adamw import AdamWConfig
 from repro.optim.schedules import make_schedule
@@ -119,12 +120,9 @@ def main(argv=None):
     ap.add_argument("--bf16-params", action="store_true")
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
-    if args.mesh:
-        mesh = mesh_from_spec(args.mesh)
-    else:
-        n = len(jax.devices())
-        mesh = jax.make_mesh((1, n), ("data", "model"))
+    mesh = mesh_from_spec(args.mesh or f"1x{len(jax.devices())}")
     train_loop(cfg, mesh, steps=args.steps, global_batch=args.batch,
                seq_len=args.seq, grad_sync=args.grad_sync,
                microbatches=args.microbatches, lr=args.lr,

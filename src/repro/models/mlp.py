@@ -34,10 +34,8 @@ def fused_ffn_supported(cfg: ModelConfig, n_rows: int, d_ff: int) -> bool:
     The kernel hard-codes silu gating (GeGLU archs fall back to the jnp
     path) and its grid needs both the flattened row count and the hidden
     width to split into equal blocks."""
-    from repro.kernels.fused_ffn import DEFAULT_BF, DEFAULT_BR
-    return (cfg.mlp_act == "silu"
-            and (n_rows <= DEFAULT_BR or n_rows % DEFAULT_BR == 0)
-            and (d_ff <= DEFAULT_BF or d_ff % DEFAULT_BF == 0))
+    from repro.kernels.fused_ffn import blocks_ok
+    return cfg.mlp_act == "silu" and blocks_ok(n_rows, d_ff)
 
 
 def mlp(x: jax.Array, params: dict, cfg: ModelConfig) -> jax.Array:

@@ -27,7 +27,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import axis_size, shard_map
 from repro.models.common import ModelConfig, MoEConfig, PSpec
 from repro.models.layers import act_fn
 from repro.models.sharding import current_rules
@@ -134,7 +133,7 @@ def _moe_ep_body(x2d, params, moe: MoEConfig, act, model_axis: str):
     Local dispatch -> all_to_all -> expert FFN -> all_to_all back -> combine."""
     T = x2d.shape[0]
     E = moe.num_experts
-    P_ = axis_size(model_axis)
+    P_ = jax.lax.axis_size(model_axis)
     E_loc = E // P_
     C = _capacity(T, moe)
     weights, top_e, aux = _route(x2d, params["router"], moe)
@@ -262,7 +261,7 @@ def moe_ffn(x: jax.Array, params: dict, cfg: ModelConfig, moe: MoEConfig):
             aux = jax.lax.pmean(aux, batch_axes)
         return yl.reshape(xl.shape), aux
 
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         mapped, mesh=mesh,
         in_specs=(x_spec, w_specs),
         out_specs=(x_spec, P()),

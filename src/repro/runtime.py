@@ -23,17 +23,19 @@ assembles the stack through one entry point instead of re-wiring
 from __future__ import annotations
 
 import contextlib
+import math
 from typing import Any, Callable, Optional, Union
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import NamedSharding
+from jax.sharding import NamedSharding, PartitionSpec
 
 from repro.configs import get_config, get_smoke_config
 from repro.core import topology
 from repro.core.topology import Plan, batch_pspec, make_plan, mesh_axes_of
 from repro.models import registry
-from repro.models.common import ModelConfig, count_params, init_params
+from repro.models.common import (ModelConfig, count_params, init_params,
+                                 is_pspec, partition_specs)
 from repro.models.sharding import activation_sharding
 from repro.serve import steps as serve_steps
 from repro.train import steps as train_steps
@@ -268,7 +270,8 @@ class Runtime:
         if self._params is None:
             self._params = init_params(self.specs,
                                        jax.random.PRNGKey(self.seed),
-                                       self.param_dtype)
+                                       self.param_dtype,
+                                       shardings=self.param_shardings)
         return self._params
 
     @params.setter
@@ -298,6 +301,28 @@ class Runtime:
             return None
         return train_state_mod.train_state_shardings(
             self.specs, self.plan, self.mesh, self.param_dtype)
+
+    @property
+    def param_shardings(self):
+        """Params NamedSharding tree by ``plan.param_rules`` (None without
+        a mesh).  A dim its rule's mesh axes do not divide stays whole."""
+        if self.mesh is None:
+            return None
+        sizes = dict(zip(self.mesh.axis_names, self.mesh.devices.shape))
+
+        def fit(spec, pspec):
+            dims = []
+            for n, ax in zip(spec.shape, tuple(pspec) + (None,) * len(
+                    spec.shape)):
+                names = (ax,) if isinstance(ax, str) else tuple(ax or ())
+                dims.append(ax if n % math.prod(sizes[a] for a in names) == 0
+                            else None)
+            return NamedSharding(self.mesh, PartitionSpec(*dims))
+
+        return jax.tree.map(
+            fit, self.specs, partition_specs(self.specs,
+                                             self.plan.param_rules),
+            is_leaf=is_pspec)
 
     @property
     def batch_sharding(self) -> Optional[NamedSharding]:
@@ -407,12 +432,17 @@ class Runtime:
         without one the model-level path is left bare so it is bit-for-bit
         the raw registry family surface (the parity contract
         tests/test_registry.py pins) — unless a non-default kernel impl was
-        requested, in which case only the impl-selection rules are
-        installed (models resolve "auto" to the same backend either way,
-        so parity is preserved)."""
-        impls = {"train_attn_impl": self.attn_impl, "ffn_impl": self.ffn_impl}
+        requested or decode resolves to a Pallas kernel, in which case only
+        the impl-selection rules are installed (models resolve "auto" to
+        the same backend either way, so parity is preserved).  The decode
+        rule makes ``decode_step`` run the kernel ``describe()`` reports,
+        as the serve engine's decode does."""
+        decode = self.decode_attn_impl
+        impls = {"train_attn_impl": self.attn_impl, "ffn_impl": self.ffn_impl,
+                 "decode_attn_impl": decode}
         if self.mesh is None:
-            if self.attn_impl == "auto" and self.ffn_impl == "auto":
+            if (self.attn_impl == "auto" and self.ffn_impl == "auto"
+                    and decode == "ref"):
                 return fn()
             with activation_sharding(impls):
                 return fn()

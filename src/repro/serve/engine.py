@@ -90,14 +90,20 @@ wrap the tick loop, and every escalation converges on live evacuation:
   mesh devices; any unhealthy report — structured ``HealthReason``, no
   string parsing — escalates straight to evacuation with the failed
   devices excluded.
-* **Straggler escalation.**  Per-tick wall times (dispatch + the
-  overlapped collection) feed a ``StragglerMonitor``; its existing
-  warn -> remesh -> abort ladder maps to log -> evacuate -> evacuate (with
+* **Straggler escalation (opt-in).**  Per-tick wall times (dispatch + the
+  overlapped collection) always feed a ``StragglerMonitor``'s histograms;
+  only an engine built with ``straggler_kw`` acts on them, mapping the
+  warn -> remesh -> abort ladder to log -> evacuate -> evacuate (with
   scripted-fault device attribution when available, else an in-place
-  rebuild).
+  rebuild).  Off by default: a tick that compiles a new program shape is
+  slow by design, and a wall-clock ladder would read it as a straggler.
 * **Bounded retry.**  A tick that *raises* is retried with exponential
   backoff up to ``tick_retries`` times — transient faults recover without
-  losing a stream — before escalating to evacuation.
+  losing a stream — before escalating to evacuation.  A step that fails
+  to trace, lower or compile is a program error, not a fault: every
+  model step is compiled ahead of its first run for each input signature
+  (``_Step``), and a refusal raises :class:`StepCompileError` at once —
+  never retried, never evacuated.
 
 **Evacuation** (``_evacuate``) never drops a stream: the in-flight token
 transfer is flushed, every live request's portable state is snapshotted
@@ -342,6 +348,45 @@ def _install_admitted_paged(caches, part, dst, slots, tok, pos, next_tok,
     return caches, tok, pos
 
 
+class StepCompileError(RuntimeError):
+    """An engine step failed to trace, lower or compile for its inputs."""
+
+
+class _Step:
+    """A jitted engine step, compiled ahead of its first run for each input
+    signature (tree structure + leaf shape/dtype/sharding) under the
+    Runtime's mesh context.  A refusal raises :class:`StepCompileError`
+    from the compile, so the tick loop can tell it from a dispatch
+    failure."""
+
+    def __init__(self, name: str, jitted, mesh_context):
+        self.name = name
+        self._jit = jitted
+        self._mesh_context = mesh_context
+        self._exe: dict = {}
+
+    def __call__(self, *args):
+        leaves, tree = jax.tree.flatten(args)
+        key = (tree, tuple((np.shape(x), getattr(x, "dtype", type(x)),
+                            getattr(x, "sharding", None)) for x in leaves))
+        exe = self._exe.get(key)
+        if exe is None:
+            try:
+                with self._mesh_context():
+                    exe = self._jit.lower(*args).compile()
+            except Exception as e:
+                raise StepCompileError(
+                    f"engine step {self.name!r} failed to compile: "
+                    f"{type(e).__name__}: {e}") from e
+            self._exe[key] = exe
+        return exe(*args)
+
+    def _cache_size(self) -> int:
+        """Programs compiled so far (one per input signature), the same
+        count ``jax.jit``'s own cache reports."""
+        return len(self._exe)
+
+
 class ServeEngine:
     """Continuous-batching engine over a ``repro.runtime.Runtime``.
 
@@ -354,7 +399,8 @@ class ServeEngine:
     checks (0 = off), ``tick_retries``/``retry_backoff_s`` bound the
     transient-failure retry loop, ``injector`` takes a ``FaultInjector``
     (defaults to parsing ``REPRO_FAULT_PLAN``; pass ``None`` to disable),
-    ``straggler_kw`` overrides the StragglerMonitor thresholds, and
+    ``straggler_kw`` arms wall-clock straggler escalation with those
+    StragglerMonitor thresholds (None = observe only), and
     ``max_evacuations`` is the give-up bound on repeated evacuation (a
     persistently failing data path must eventually surface, not loop).
 
@@ -499,9 +545,12 @@ class ServeEngine:
         self.tick_retries = tick_retries
         self.retry_backoff_s = retry_backoff_s
         self.max_evacuations = max_evacuations
-        # Serving-tuned thresholds: decode ticks are short and noisy on a
-        # shared host, so ratios sit far above the training defaults and
-        # the first (compile-spiked) ticks land inside the warmup window.
+        # Tick times always feed the monitor's histograms; only an
+        # explicit ``straggler_kw`` lets its ladder evacuate.  The observe-
+        # only thresholds are serving-tuned: decode ticks are short and
+        # noisy on a shared host, so ratios sit far above the training
+        # defaults.
+        self._straggler_escalates = straggler_kw is not None
         self.straggler = StragglerMonitor(registry=self.obs.registry, **(
             straggler_kw if straggler_kw is not None
             else dict(window=32, warn_ratio=4.0, remesh_ratio=10.0,
@@ -578,11 +627,15 @@ class ServeEngine:
         # reads block columns out of the same program's caches, so dense
         # and paged engines see bitwise-identical prefill K/V (the
         # token-parity contract tests/test_paged.py pins down).
-        # ``rt._bind_mesh`` wraps each executable so tracing happens under
-        # the Runtime's mesh context (sharding-annotated model code needs
-        # an ambient mesh for its bare-PartitionSpec constraints).
-        self._prefill = rt._bind_mesh(
-            jax.jit(rt.make_prefill_step(capacity=self.capacity)))
+        # Model steps are ``_Step``s: compiled ahead of each new input
+        # signature under the Runtime's mesh context (sharding-annotated
+        # model code needs an ambient mesh for its bare-PartitionSpec
+        # constraints), so a compile refusal never looks like a fault.
+        def step(name, fn, **kw):
+            return _Step(name, jax.jit(fn, **kw), rt.mesh_context)
+
+        self._prefill = step("prefill",
+                             rt.make_prefill_step(capacity=self.capacity))
         if self.paged:
             # block pool sized for the worst case (every slot at capacity)
             # unless told tighter; +reserved null/trash blocks.
@@ -599,28 +652,33 @@ class ServeEngine:
                                             registry=self.obs.registry)
             self.caches = blockpool.init_paged_cache(self.cfg, nblocks, bs,
                                                      kv_dtype=self.kv_dtype)
-            decode = rt.make_paged_decode_step(attn_impl=self._attn_impl,
-                                               kv_dtype=self.kv_dtype)
-            self._decode = rt._bind_mesh(jax.jit(decode, **donate_kw))
+            self._decode = step(
+                "paged_decode",
+                rt.make_paged_decode_step(attn_impl=self._attn_impl,
+                                          kv_dtype=self.kv_dtype),
+                **donate_kw)
             self._splice = jax.jit(_install_admitted_paged, **splice_kw)
             self._copy = jax.jit(blockpool.copy_blocks, **splice_kw)
             if self.scheduler:
-                self._mixed = rt._bind_mesh(jax.jit(
+                self._mixed = step(
+                    "paged_mixed",
                     rt.make_paged_mixed_step(attn_impl=self._attn_impl,
                                              kv_dtype=self.kv_dtype),
-                    **donate_kw))
+                    **donate_kw)
         else:
             self.pool = None
             self.caches = kvcache.init_cache(self.cfg, self.num_slots,
                                              self.capacity)
-            decode = rt.make_decode_step(attn_impl=self._attn_impl,
-                                         advance_pos=True)
-            self._decode = rt._bind_mesh(jax.jit(decode, **donate_kw))
+            self._decode = step(
+                "decode",
+                rt.make_decode_step(attn_impl=self._attn_impl,
+                                    advance_pos=True),
+                **donate_kw)
             self._splice = jax.jit(_install_admitted, **splice_kw)
             if self.scheduler:
-                self._mixed = rt._bind_mesh(jax.jit(
-                    rt.make_mixed_step(attn_impl=self._attn_impl),
-                    **donate_kw))
+                self._mixed = step(
+                    "mixed", rt.make_mixed_step(attn_impl=self._attn_impl),
+                    **donate_kw)
         # footprint gauges: allocation-static per build (the pool is sized
         # up front), so one sync here covers the engine's lifetime
         self._g_kv_bytes.set(self.kv_cache_bytes())
@@ -1071,13 +1129,17 @@ class ServeEngine:
         to evacuation.  Scripted faults fire via ``injector.on_tick``
         *before* the jitted step, so a failed attempt never half-consumes
         the donated cache buffers (the paged write plan likewise only
-        advances inside a successful ``_dispatch``)."""
+        advances inside a successful ``_dispatch``).  A compile refusal
+        (:class:`StepCompileError`) is a program error and propagates at
+        once."""
         last = None
         for attempt in range(self.tick_retries + 1):
             try:
                 if self.injector is not None:
                     self.injector.on_tick(t)
                 return self._dispatch()
+            except StepCompileError:
+                raise
             except Exception as e:  # noqa: BLE001 — retry, then escalate
                 last = e
                 self.stats.tick_retries += 1
@@ -1104,8 +1166,9 @@ class ServeEngine:
         Fault tolerance wraps the loop: on the ``health_every`` cadence the
         tick first consults ``ft.health.check_devices`` (with scripted
         faults overlaid), the dispatch is retried with backoff on transient
-        failures, and the tick wall time feeds the ``StragglerMonitor``;
-        every escalation converges on :meth:`_evacuate`.
+        failures, and the tick wall time feeds the ``StragglerMonitor``
+        (which escalates only when armed with ``straggler_kw``); every
+        escalation converges on :meth:`_evacuate`.
 
         Observability wraps it once more: the whole tick is a ``tick``
         span with ``plan`` / ``dispatch`` / ``collect`` / ``admit`` (and
@@ -1159,7 +1222,7 @@ class ServeEngine:
                 # the tick critical path (dispatch + overlapped collection)
                 rep = self.straggler.observe(t,
                                              time.perf_counter() - t_start)
-                if rep.action != "ok":
+                if rep.action != "ok" and self._straggler_escalates:
                     self._on_straggler(t, rep)
 
         if self.scrub_every and t % self.scrub_every == 0:

@@ -317,6 +317,31 @@ def test_repeated_evacuation_gives_up():
         eng.run_to_completion()
 
 
+def test_step_compile_error_raises_without_retry_or_evacuation():
+    """A step that fails to lower is a program error, not a fault: it
+    raises at the first dispatch and never walks the retry/evacuation
+    ladder.  Compiled (non-interpret) Pallas has no CPU lowering, so the
+    forced-Pallas decode step is refused here the way a Mosaic refusal
+    would be on a chip."""
+    from repro.kernels import ops
+    from repro.serve.engine import StepCompileError
+    cfg = _cfg()
+    rt = Runtime.create(cfg, shape_kind="decode", capacity=32)
+    eng = rt.engine(num_slots=2, attn_impl="pallas", injector=None,
+                    retry_backoff_s=0.0)
+    for r in _stream(cfg):
+        eng.submit(r)
+    ops.set_interpret_mode(False)
+    try:
+        with pytest.raises(StepCompileError, match="'decode'"):
+            eng.run_to_completion()
+    finally:
+        ops.set_interpret_mode(None)
+    assert eng.stats.tick_retries == 0
+    assert eng.stats.evacuations == 0
+    assert eng.ft_events == []
+
+
 def test_engine_injector_defaults_from_env(monkeypatch):
     cfg = _cfg()
     monkeypatch.setenv("REPRO_FAULT_PLAN", "tick=3,kind=raise")

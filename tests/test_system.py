@@ -49,6 +49,39 @@ def test_serve_launcher_end_to_end():
     assert "finished=4" in r.stdout
 
 
+CACHE_CODE = """
+import jax, jax.numpy as jnp
+from repro.launch.compile_cache import enable_compile_cache
+print(enable_compile_cache())
+print(jax.config.jax_compilation_cache_dir)
+if {compile!r}:
+    jax.jit(lambda x: jnp.sin(x) * 2 + 1)(jnp.ones(64)).block_until_ready()
+"""
+
+
+def test_compile_cache_goes_where_the_env_says(tmp_path):
+    """JAX_COMPILATION_CACHE_DIR set: the cache is written there and
+    nothing else is configured."""
+    cache = tmp_path / "cache"
+    env = dict(ENV1, JAX_COMPILATION_CACHE_DIR=str(cache),
+               JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0",
+               JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES="0")
+    r = _run(CACHE_CODE.format(compile=True), env)
+    assert r.returncode == 0, r.stderr[-2000:]
+    assert r.stdout.split() == [str(cache), str(cache)]
+    assert any(cache.iterdir())
+
+
+def test_compile_cache_defaults_to_fixed_checkout_path():
+    """Unset: a fixed directory at the root of the checkout — no temp dir,
+    pid or time in it, so the next run finds the same cache."""
+    env = {k: v for k, v in ENV1.items() if k != "JAX_COMPILATION_CACHE_DIR"}
+    r = _run(CACHE_CODE.format(compile=False), env)
+    assert r.returncode == 0, r.stderr[-2000:]
+    want = os.path.join(REPO, ".jax_cache")
+    assert r.stdout.split() == [want, want]
+
+
 GRAD_SYNC_CODE = """
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
@@ -56,11 +89,12 @@ import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import NamedSharding
 from repro.configs import get_smoke_config
 from repro.core.topology import make_plan, batch_pspec
+from repro.launch.mesh import mesh_from_spec
 from repro.models.registry import model_specs
 from repro.train.state import init_train_state, train_state_shardings
 from repro.train.steps import make_train_step
 
-mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh = mesh_from_spec("2x2x2")
 cfg = get_smoke_config("{arch}")
 specs = model_specs(cfg)
 results = {{}}
