@@ -474,7 +474,7 @@ def main(smoke: bool = False, kv_layout: str = "dense",
         ct = json.load(f)
     evs = ct["traceEvents"]
     assert evs, "traced run exported an empty trace"
-    assert all(e["ph"] in ("X", "i") and "ts" in e for e in evs)
+    assert all(e["ph"] in ("X", "i", "b", "e") and "ts" in e for e in evs)
     assert any(e["name"] == "tick" and "dur" in e for e in evs), \
         "no complete tick spans in the exported trace"
     assert traced_wall <= bare_wall * 1.05 + 0.05, \

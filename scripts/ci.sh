@@ -154,7 +154,7 @@ import json
 ct = json.load(open("BENCH_serve_trace.json"))
 evs = ct["traceEvents"]
 assert evs, "trace has no events"
-assert all(e["ph"] in ("X", "i") and "ts" in e for e in evs)
+assert all(e["ph"] in ("X", "i", "b", "e") and "ts" in e for e in evs)
 ticks = [e for e in evs if e["name"] == "tick" and e["ph"] == "X"]
 assert ticks and all(e["dur"] > 0 for e in ticks)
 print(f"trace artifact OK: {len(evs)} events, {len(ticks)} tick spans")

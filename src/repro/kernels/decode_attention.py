@@ -138,6 +138,7 @@ def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     )
     out = pl.pallas_call(
         kernel,
+        name="decode_attention",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KV, G, D), q.dtype),
         interpret=interpret,

@@ -154,6 +154,7 @@ def _forward(q, k, v, causal, window, bq, bk, interpret, with_lse=True):
         out_shape.append(jax.ShapeDtypeStruct((B, H, 1, S), jnp.float32))
     res = pl.pallas_call(
         kernel,
+        name="flash_attention",
         grid=(B, H, S // bq),
         in_specs=[
             pl.BlockSpec((None, None, bq, D), lambda b, h, i: (b, h, i, 0)),
@@ -265,6 +266,7 @@ def _backward(q, k, v, o, lse, g, causal, window, bq, bk, interpret):
     row_spec = pl.BlockSpec((None, None, 1, bq), lambda b, h, i: (b, h, 0, i))
     dq = pl.pallas_call(
         dq_kernel,
+        name="flash_attention_bwd_dq",
         grid=(B, H, S // bq),
         in_specs=[
             pl.BlockSpec((None, None, bq, D), lambda b, h, i: (b, h, i, 0)),
@@ -288,6 +290,7 @@ def _backward(q, k, v, o, lse, g, causal, window, bq, bk, interpret):
                             lambda b, h, j, i: (b, h, 0, i))
     dk, dv = pl.pallas_call(
         dkv_kernel,
+        name="flash_attention_bwd_dkv",
         grid=(B, H, T // bk, S // bq),
         in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
         out_specs=[kv_spec, kv_spec],

@@ -123,6 +123,7 @@ def _forward(x, w_gate, w_up, w_down, br, bf, interpret):
               + 3 * _nbytes((D, bf), w_gate.dtype))
     return pl.pallas_call(
         _ffn_kernel,
+        name="swiglu_ffn",
         grid=(N // br, F // bf),
         in_specs=[
             pl.BlockSpec((br, D), lambda i, j: (i, 0)),
@@ -212,6 +213,7 @@ def _backward(x, w_gate, w_up, w_down, dy, br, bf, interpret):
 
     dx = pl.pallas_call(
         _bwd_dx_kernel,
+        name="swiglu_ffn_bwd_dx",
         grid=(N // br, F // bf),
         in_specs=[
             pl.BlockSpec((br, D), lambda i, j: (i, 0)),
@@ -230,6 +232,7 @@ def _backward(x, w_gate, w_up, w_down, dy, br, bf, interpret):
 
     dwg, dwu, dwd = pl.pallas_call(
         _bwd_dw_kernel,
+        name="swiglu_ffn_bwd_dw",
         grid=(F // bf, N // br),
         in_specs=[
             pl.BlockSpec((br, D), lambda j, i: (i, 0)),

@@ -80,6 +80,7 @@ def mlstm_scan(q: jax.Array, k: jax.Array, v: jax.Array,
     kernel = functools.partial(_mlstm_kernel, L=L)
     return pl.pallas_call(
         kernel,
+        name="mlstm_scan",
         grid=(B, H, nc),
         in_specs=[
             pl.BlockSpec((None, None, L, dh), lambda b, h, c: (b, h, c, 0)),

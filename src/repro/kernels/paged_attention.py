@@ -82,10 +82,11 @@ def _paged_q8_kernel(tbl_ref, pos_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
                 k_scale=ks_ref[...], v_scale=vs_ref[...])
 
 
-def _paged_call(kernel, q, pools, extra_rows, pos_pool, block_table, pos,
-                interpret):
+def _paged_call(kernel, name, q, pools, extra_rows, pos_pool, block_table,
+                pos, interpret):
     """Shared pallas_call plumbing: ``pools`` are the [N,bs,KV,D] K/V pools,
-    ``extra_rows`` per-block rows (scales) viewed as [N,1,n]."""
+    ``extra_rows`` per-block rows (scales) viewed as [N,1,n]; ``name`` is
+    the kernel's name in the compiled program."""
     B, H, D = q.shape
     N, bs, KV = pools[0].shape[:3]
     M = block_table.shape[1]
@@ -110,6 +111,7 @@ def _paged_call(kernel, q, pools, extra_rows, pos_pool, block_table, pos,
     )
     out = pl.pallas_call(
         functools.partial(kernel, scale=D ** -0.5),
+        name=name,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KV, G, D), q.dtype),
         interpret=interpret,
@@ -128,8 +130,9 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
     """q [B,H,D]; k_pool/v_pool [N,bs,KV,D] (grouped heads);
     pos_pool [N,bs] int32 (-1 = empty); block_table [B,M] int32;
     pos [B] int32 -> [B,H,D]."""
-    return _paged_call(_paged_kernel, q, (k_pool, v_pool), (), pos_pool,
-                       block_table, pos, interpret)
+    return _paged_call(_paged_kernel, "paged_decode_attention", q,
+                       (k_pool, v_pool), (), pos_pool, block_table, pos,
+                       interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -143,6 +146,6 @@ def paged_decode_attention_q8(q: jax.Array, k_pool: jax.Array,
     pos_pool [N,bs] int32 (-1 = empty); block_table [B,M] int32; pos [B]
     int32 -> [B,H,D].  The scales ride the same block-table indirection
     as the K/V tiles and dequant happens in-loop in VMEM."""
-    return _paged_call(_paged_q8_kernel, q, (k_pool, v_pool),
-                       (k_scale, v_scale), pos_pool, block_table, pos,
-                       interpret)
+    return _paged_call(_paged_q8_kernel, "paged_decode_attention_q8", q,
+                       (k_pool, v_pool), (k_scale, v_scale), pos_pool,
+                       block_table, pos, interpret)

@@ -76,6 +76,7 @@ def _quantize_int8(x, *, block, rows, interpret):
     assert nb % rows == 0 and x.shape[1] == block
     q, s = pl.pallas_call(
         _quant_kernel,
+        name="_quantize_int8",
         grid=(nb // rows,),
         in_specs=[pl.BlockSpec((rows, block), lambda i: (i, 0))],
         out_specs=[pl.BlockSpec((rows, block), lambda i: (i, 0)),
@@ -105,6 +106,7 @@ def _dequantize_int8(q, scale, *, rows, interpret):
     assert nb % rows == 0
     return pl.pallas_call(
         _dequant_kernel,
+        name="_dequantize_int8",
         grid=(nb // rows,),
         in_specs=[pl.BlockSpec((rows, block), lambda i: (i, 0)),
                   pl.BlockSpec((rows, 1), lambda i: (i, 0))],

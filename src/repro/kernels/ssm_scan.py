@@ -76,6 +76,7 @@ def ssm_chunk_scan(dt: jax.Array, B_ssm: jax.Array, C_ssm: jax.Array,
     kernel = functools.partial(_ssm_kernel, L=L, N=N)
     y, h = pl.pallas_call(
         kernel,
+        name="ssm_chunk_scan",
         grid=(B, Di // dblk, S // L),
         in_specs=[
             pl.BlockSpec((None, L, dblk), lambda b, d, c: (b, c, d)),

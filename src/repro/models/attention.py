@@ -349,9 +349,10 @@ def attention_decode(x: jax.Array, params: dict, cfg: ModelConfig, *,
         if write_idx is None:
             write_idx = pos
         b = jnp.arange(B)
-        k_cache = k_cache.at[b, write_idx].set(k_new[:, 0])
-        v_cache = v_cache.at[b, write_idx].set(v_new[:, 0])
-        kv_positions = kv_positions.at[b, write_idx].set(pos)
+        with jax.named_scope("kv_update"):
+            k_cache = k_cache.at[b, write_idx].set(k_new[:, 0])
+            v_cache = v_cache.at[b, write_idx].set(v_new[:, 0])
+            kv_positions = kv_positions.at[b, write_idx].set(pos)
 
     rules = current_rules() or {}
     if (rules.get("decode_attn_impl") == "pallas"
@@ -472,18 +473,19 @@ def attention_decode_paged(x: jax.Array, params: dict, cfg: ModelConfig, *,
     # otherwise pass the positional mask as phantoms — clear the block's
     # position row before writing into it.  (Quantized pools reset the
     # block's stale *scale* the same way, inside _quantized_block_write.)
-    prow = pos_pool[write_bids]                             # [B, bs]
-    pos_pool = pos_pool.at[write_bids].set(
-        jnp.where((off == 0)[:, None], -1, prow))
-    if quantized:
-        k_pool, k_scale_pool = _quantized_block_write(
-            k_pool, k_scale_pool, k_new[:, 0], write_bids, off)
-        v_pool, v_scale_pool = _quantized_block_write(
-            v_pool, v_scale_pool, v_new[:, 0], write_bids, off)
-    else:
-        k_pool = k_pool.at[write_bids, off].set(k_new[:, 0])
-        v_pool = v_pool.at[write_bids, off].set(v_new[:, 0])
-    pos_pool = pos_pool.at[write_bids, off].set(pos)
+    with jax.named_scope("kv_update"):
+        prow = pos_pool[write_bids]                         # [B, bs]
+        pos_pool = pos_pool.at[write_bids].set(
+            jnp.where((off == 0)[:, None], -1, prow))
+        if quantized:
+            k_pool, k_scale_pool = _quantized_block_write(
+                k_pool, k_scale_pool, k_new[:, 0], write_bids, off)
+            v_pool, v_scale_pool = _quantized_block_write(
+                v_pool, v_scale_pool, v_new[:, 0], write_bids, off)
+        else:
+            k_pool = k_pool.at[write_bids, off].set(k_new[:, 0])
+            v_pool = v_pool.at[write_bids, off].set(v_new[:, 0])
+        pos_pool = pos_pool.at[write_bids, off].set(pos)
 
     rules = current_rules() or {}
     impl = rules.get("decode_attn_impl")
@@ -569,11 +571,12 @@ def attention_chunk_append(x: jax.Array, params: dict, cfg: ModelConfig, *,
     B = x.shape[0]
     q, k_new, v_new = _project_chunk_kv(x, params, cfg, positions)
 
-    kv_positions = jnp.where(reset[:, None], -1, kv_positions)
     b = jnp.arange(B)[:, None]
-    k_cache = k_cache.at[b, positions].set(k_new)
-    v_cache = v_cache.at[b, positions].set(v_new)
-    kv_positions = kv_positions.at[b, positions].set(positions)
+    with jax.named_scope("kv_update"):
+        kv_positions = jnp.where(reset[:, None], -1, kv_positions)
+        k_cache = k_cache.at[b, positions].set(k_new)
+        v_cache = v_cache.at[b, positions].set(v_new)
+        kv_positions = kv_positions.at[b, positions].set(positions)
 
     out = _jnp_decode_attend(q, k_cache, v_cache, kv_positions, positions,
                              cfg)
@@ -623,16 +626,17 @@ def attention_chunk_append_paged(x: jax.Array, params: dict,
     # trash block (unobservable); tokens past offset 0 redirect their clear
     # there too (TRASH_BLOCK = 1, serve/blockpool.py)
     clear = jnp.where(off == 0, write_bids, jnp.ones_like(write_bids))
-    pos_pool = pos_pool.at[clear].set(-1)
-    if quantized:
-        k_pool, k_scale_pool = _quantized_block_write(
-            k_pool, k_scale_pool, k_new, write_bids, off)
-        v_pool, v_scale_pool = _quantized_block_write(
-            v_pool, v_scale_pool, v_new, write_bids, off)
-    else:
-        k_pool = k_pool.at[write_bids, off].set(k_new)
-        v_pool = v_pool.at[write_bids, off].set(v_new)
-    pos_pool = pos_pool.at[write_bids, off].set(positions)
+    with jax.named_scope("kv_update"):
+        pos_pool = pos_pool.at[clear].set(-1)
+        if quantized:
+            k_pool, k_scale_pool = _quantized_block_write(
+                k_pool, k_scale_pool, k_new, write_bids, off)
+            v_pool, v_scale_pool = _quantized_block_write(
+                v_pool, v_scale_pool, v_new, write_bids, off)
+        else:
+            k_pool = k_pool.at[write_bids, off].set(k_new)
+            v_pool = v_pool.at[write_bids, off].set(v_new)
+        pos_pool = pos_pool.at[write_bids, off].set(positions)
 
     flat = block_table.reshape(-1)
     if quantized:

@@ -93,39 +93,43 @@ def block_forward(kind: str, x, p, cfg: ModelConfig, *, positions,
     cache = None
     h = rmsnorm(x, p["norm1"], cfg.norm_eps)
     if kind.startswith("attn"):
-        if collect_cache:
-            a, (k, v) = attention(h, p["attn"], cfg, positions=positions,
-                                  causal=causal and kind != "attn_nc",
-                                  mode=attn_mode, return_kv=True)
-            if cfg.sliding_window is not None and \
-                    k.shape[1] > cfg.sliding_window:
-                # SWA: only the last `window` entries can ever be attended
-                # again — trimming here keeps the per-layer prefill cache
-                # O(window), not O(S) (the 32k mixtral prefill cell)
-                k = k[:, -cfg.sliding_window:]
-                v = v[:, -cfg.sliding_window:]
-            cache = {"k": k, "v": v}
-        else:
-            a = attention(h, p["attn"], cfg, positions=positions,
-                          causal=causal and kind != "attn_nc", mode=attn_mode)
-        x = x + a
-        if kind == "attn_cross":
-            hx = rmsnorm(x, p["norm_x"], cfg.norm_eps)
+        with jax.named_scope("attn"):
             if collect_cache:
-                a2, (xk, xv) = attention(hx, p["xattn"], cfg, kv_x=memory,
-                                         causal=False, mode=attn_mode,
-                                         return_kv=True)
-                cache.update({"xk": xk, "xv": xv})
-                x = x + a2
+                a, (k, v) = attention(h, p["attn"], cfg, positions=positions,
+                                      causal=causal and kind != "attn_nc",
+                                      mode=attn_mode, return_kv=True)
+                if cfg.sliding_window is not None and \
+                        k.shape[1] > cfg.sliding_window:
+                    # SWA: only the last `window` entries can ever be
+                    # attended again — trimming here keeps the per-layer
+                    # prefill cache O(window), not O(S) (the 32k mixtral
+                    # prefill cell)
+                    k = k[:, -cfg.sliding_window:]
+                    v = v[:, -cfg.sliding_window:]
+                cache = {"k": k, "v": v}
             else:
-                x = x + attention(hx, p["xattn"], cfg, kv_x=memory,
-                                  causal=False, mode=attn_mode)
-        h2 = rmsnorm(x, p["norm2"], cfg.norm_eps)
-        if kind == "attn_moe":
-            f, aux = moe_ffn(h2, p["ffn"], cfg, cfg.moe)
-        else:
-            f = mlp(h2, p["ffn"], cfg)
-        x = x + f
+                a = attention(h, p["attn"], cfg, positions=positions,
+                              causal=causal and kind != "attn_nc",
+                              mode=attn_mode)
+            x = x + a
+            if kind == "attn_cross":
+                hx = rmsnorm(x, p["norm_x"], cfg.norm_eps)
+                if collect_cache:
+                    a2, (xk, xv) = attention(hx, p["xattn"], cfg,
+                                             kv_x=memory, causal=False,
+                                             mode=attn_mode, return_kv=True)
+                    cache.update({"xk": xk, "xv": xv})
+                    x = x + a2
+                else:
+                    x = x + attention(hx, p["xattn"], cfg, kv_x=memory,
+                                      causal=False, mode=attn_mode)
+        with jax.named_scope("ffn"):
+            h2 = rmsnorm(x, p["norm2"], cfg.norm_eps)
+            if kind == "attn_moe":
+                f, aux = moe_ffn(h2, p["ffn"], cfg, cfg.moe)
+            else:
+                f = mlp(h2, p["ffn"], cfg)
+            x = x + f
     elif kind.startswith("mamba"):
         if collect_cache:
             m, (hstate, buf) = ssm_mod.mamba(h, p["mixer"], cfg, cfg.ssm,
@@ -212,38 +216,44 @@ def block_decode(kind: str, x, p, cfg: ModelConfig, cache: dict, *,
     path."""
     h = rmsnorm(x, p["norm1"], cfg.norm_eps)
     if kind.startswith("attn"):
-        if paged is not None:
-            if "k_scale" in cache:      # int8 pool: scale leaves ride along
-                a, kc, vc, kp, ksc, vsc = attention_decode_paged(
-                    h, p["attn"], cfg, k_pool=cache["k"], v_pool=cache["v"],
-                    pos_pool=cache["pos"], block_table=paged["block_table"],
-                    write_bids=paged["write_bids"], pos=pos,
-                    k_scale_pool=cache["k_scale"],
-                    v_scale_pool=cache["v_scale"])
-                cache = dict(cache, k_scale=ksc, v_scale=vsc)
+        with jax.named_scope("attn"):
+            if paged is not None:
+                if "k_scale" in cache:  # int8 pool: scale leaves ride along
+                    a, kc, vc, kp, ksc, vsc = attention_decode_paged(
+                        h, p["attn"], cfg, k_pool=cache["k"],
+                        v_pool=cache["v"], pos_pool=cache["pos"],
+                        block_table=paged["block_table"],
+                        write_bids=paged["write_bids"], pos=pos,
+                        k_scale_pool=cache["k_scale"],
+                        v_scale_pool=cache["v_scale"])
+                    cache = dict(cache, k_scale=ksc, v_scale=vsc)
+                else:
+                    a, kc, vc, kp = attention_decode_paged(
+                        h, p["attn"], cfg, k_pool=cache["k"],
+                        v_pool=cache["v"], pos_pool=cache["pos"],
+                        block_table=paged["block_table"],
+                        write_bids=paged["write_bids"], pos=pos)
             else:
-                a, kc, vc, kp = attention_decode_paged(
-                    h, p["attn"], cfg, k_pool=cache["k"], v_pool=cache["v"],
-                    pos_pool=cache["pos"], block_table=paged["block_table"],
-                    write_bids=paged["write_bids"], pos=pos)
-        else:
-            a, kc, vc, kp = attention_decode(
-                h, p["attn"], cfg, k_cache=cache["k"], v_cache=cache["v"],
-                kv_positions=cache["pos"], pos=pos, write_idx=write_idx)
-        cache = dict(cache, k=kc, v=vc, pos=kp)
-        x = x + a
-        if kind == "attn_cross":
-            hx = rmsnorm(x, p["norm_x"], cfg.norm_eps)
-            a2, _, _, _ = attention_decode(
-                hx, p["xattn"], cfg, k_cache=cache["xk"], v_cache=cache["xv"],
-                kv_positions=cache["xpos"], pos=pos, cross=True)
-            x = x + a2
-        h2 = rmsnorm(x, p["norm2"], cfg.norm_eps)
-        if kind == "attn_moe":
-            f, _ = moe_ffn(h2, p["ffn"], cfg, cfg.moe)
-        else:
-            f = mlp(h2, p["ffn"], cfg)
-        x = x + f
+                a, kc, vc, kp = attention_decode(
+                    h, p["attn"], cfg, k_cache=cache["k"],
+                    v_cache=cache["v"], kv_positions=cache["pos"], pos=pos,
+                    write_idx=write_idx)
+            cache = dict(cache, k=kc, v=vc, pos=kp)
+            x = x + a
+            if kind == "attn_cross":
+                hx = rmsnorm(x, p["norm_x"], cfg.norm_eps)
+                a2, _, _, _ = attention_decode(
+                    hx, p["xattn"], cfg, k_cache=cache["xk"],
+                    v_cache=cache["xv"], kv_positions=cache["xpos"],
+                    pos=pos, cross=True)
+                x = x + a2
+        with jax.named_scope("ffn"):
+            h2 = rmsnorm(x, p["norm2"], cfg.norm_eps)
+            if kind == "attn_moe":
+                f, _ = moe_ffn(h2, p["ffn"], cfg, cfg.moe)
+            else:
+                f = mlp(h2, p["ffn"], cfg)
+            x = x + f
     elif kind.startswith("mamba"):
         m, hs, buf = ssm_mod.mamba_decode(h, p["mixer"], cfg, cfg.ssm,
                                           cache["h"], cache["conv"])
@@ -316,32 +326,34 @@ def block_chunk(kind: str, x, p, cfg: ModelConfig, cache: dict, *,
             f"chunked prefill only supports self-attention blocks; "
             f"got block kind {kind!r}")
     h = rmsnorm(x, p["norm1"], cfg.norm_eps)
-    if paged is not None:
-        if "k_scale" in cache:          # int8 pool: scale leaves ride along
-            a, kc, vc, kp, ksc, vsc = attention_chunk_append_paged(
-                h, p["attn"], cfg, k_pool=cache["k"], v_pool=cache["v"],
-                pos_pool=cache["pos"], block_table=paged["block_table"],
-                write_bids=paged["write_bids"], positions=positions,
-                k_scale_pool=cache["k_scale"],
-                v_scale_pool=cache["v_scale"])
-            cache = dict(cache, k_scale=ksc, v_scale=vsc)
+    with jax.named_scope("attn"):
+        if paged is not None:
+            if "k_scale" in cache:      # int8 pool: scale leaves ride along
+                a, kc, vc, kp, ksc, vsc = attention_chunk_append_paged(
+                    h, p["attn"], cfg, k_pool=cache["k"], v_pool=cache["v"],
+                    pos_pool=cache["pos"], block_table=paged["block_table"],
+                    write_bids=paged["write_bids"], positions=positions,
+                    k_scale_pool=cache["k_scale"],
+                    v_scale_pool=cache["v_scale"])
+                cache = dict(cache, k_scale=ksc, v_scale=vsc)
+            else:
+                a, kc, vc, kp = attention_chunk_append_paged(
+                    h, p["attn"], cfg, k_pool=cache["k"], v_pool=cache["v"],
+                    pos_pool=cache["pos"], block_table=paged["block_table"],
+                    write_bids=paged["write_bids"], positions=positions)
         else:
-            a, kc, vc, kp = attention_chunk_append_paged(
-                h, p["attn"], cfg, k_pool=cache["k"], v_pool=cache["v"],
-                pos_pool=cache["pos"], block_table=paged["block_table"],
-                write_bids=paged["write_bids"], positions=positions)
-    else:
-        a, kc, vc, kp = attention_chunk_append(
-            h, p["attn"], cfg, k_cache=cache["k"], v_cache=cache["v"],
-            kv_positions=cache["pos"], positions=positions, reset=reset)
-    cache = dict(cache, k=kc, v=vc, pos=kp)
-    x = x + a
-    h2 = rmsnorm(x, p["norm2"], cfg.norm_eps)
-    if kind == "attn_moe":
-        f, _ = moe_ffn(h2, p["ffn"], cfg, cfg.moe)
-    else:
-        f = mlp(h2, p["ffn"], cfg)
-    x = x + f
+            a, kc, vc, kp = attention_chunk_append(
+                h, p["attn"], cfg, k_cache=cache["k"], v_cache=cache["v"],
+                kv_positions=cache["pos"], positions=positions, reset=reset)
+        cache = dict(cache, k=kc, v=vc, pos=kp)
+        x = x + a
+    with jax.named_scope("ffn"):
+        h2 = rmsnorm(x, p["norm2"], cfg.norm_eps)
+        if kind == "attn_moe":
+            f, _ = moe_ffn(h2, p["ffn"], cfg, cfg.moe)
+        else:
+            f = mlp(h2, p["ffn"], cfg)
+        x = x + f
     return x, cache
 
 

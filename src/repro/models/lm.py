@@ -80,9 +80,10 @@ def lm_forward(params, tokens, cfg: ModelConfig, *,
             x, last_index.astype(jnp.int32)[:, None, None], axis=1)
     elif last_only:
         x = x[:, -1:]
-    x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
-    logits = lm_head(x, _unembed_table(params, cfg), cfg)
-    logits = shard(logits, "batch", None, "vocab_act")
+    with jax.named_scope("head"):
+        x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
+        logits = lm_head(x, _unembed_table(params, cfg), cfg)
+        logits = shard(logits, "batch", None, "vocab_act")
     return logits, aux, caches
 
 
@@ -148,8 +149,9 @@ def lm_chunk_prefill(params, tokens, caches, cfg: ModelConfig, *,
                                  paged=paged)
     x = jnp.take_along_axis(
         x, last_index.astype(jnp.int32)[:, None, None], axis=1)
-    x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
-    logits = lm_head(x, _unembed_table(params, cfg), cfg)
+    with jax.named_scope("head"):
+        x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
+        logits = lm_head(x, _unembed_table(params, cfg), cfg)
     return logits, caches
 
 
@@ -163,6 +165,7 @@ def lm_decode_step(params, token, caches, cfg: ModelConfig, *,
                positions=pos[:, None] if cfg.pos_emb == "learned" else None)
     x, caches = run_groups_decode(x, params["groups"], caches, cfg,
                                   pos=pos, write_idx=write_idx, paged=paged)
-    x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
-    logits = lm_head(x, _unembed_table(params, cfg), cfg)
+    with jax.named_scope("head"):
+        x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
+        logits = lm_head(x, _unembed_table(params, cfg), cfg)
     return logits, caches

@@ -1,6 +1,7 @@
 """Pluggable exporters: JSONL event streams, metric dumps, trace files.
 
-Three consumers share these helpers:
+Three consumers share these helpers (the Chrome trace file itself is
+``Tracer.export_chrome``):
 
 - ``launch/serve.py`` — ``--events-out`` streams the engine's ft events
   as machine-parseable JSONL (one JSON object per line, default stdout),
@@ -20,7 +21,6 @@ from typing import IO, Iterable, Mapping
 __all__ = [
     "JsonlExporter",
     "dump_metrics",
-    "export_chrome_trace",
     "write_events_jsonl",
 ]
 
@@ -105,8 +105,3 @@ def dump_metrics(registry, path: str, fmt: str | None = None) -> str:
         with open(path, "w") as f:
             f.write(body)
     return path
-
-
-def export_chrome_trace(tracer, path: str) -> str:
-    """Write the tracer's ring buffer as a Chrome ``trace_event`` file."""
-    return tracer.export_chrome(path)

@@ -847,10 +847,33 @@ class ServeEngine:
         ``slots``.  The batch is padded to a power-of-two row count by
         repeating the last request (bounded recompilation); pad rows write
         the same payload to the same slot."""
-        B = len(group)
         now = time.perf_counter()
-        for r in group:
+        for s, r in zip(slots, group):
             r.admitted_at = now          # queue exit: prefill starts here
+            self.tracer.record("req:queued", r.submitted_at, now, id=r.rid,
+                               slot=s)
+        with self.tracer.span("admit:prefill", tick=self._tick_no):
+            next_tok = self._prefill_and_splice(slots, group, blen)
+        with self.tracer.span("admit:wait", tick=self._tick_no):
+            first = np.asarray(jax.device_get(next_tok)).reshape(-1)
+        now = time.perf_counter()
+        for i, (s, r) in enumerate(zip(slots, group)):
+            self.slot_req[s] = r
+            self.slot_pos[s] = len(r.prompt)
+            self._slot_gen[s] += 1    # fresh occupant: stale seals invalid
+            tok = int(first[i])
+            r.generated.append(tok)
+            r.first_token_at = now
+            self.stats.admitted += 1
+            self.tracer.instant("req:admit", rid=r.rid, slot=s)
+            if len(r.generated) >= r.max_new_tokens or tok == r.eos_id:
+                self._free(s)     # degenerate: done at prefill
+
+    def _prefill_and_splice(self, slots: list, group: list, blen: int):
+        """Build the padded batch, dispatch its prefill and the splice of
+        its caches into ``slots``; returns the prefill's first tokens (on
+        the device)."""
+        B = len(group)
         Bp = 1 << (B - 1).bit_length()
         toks = np.zeros((Bp, blen), np.int32)
         lens = np.zeros(Bp, np.int32)
@@ -882,19 +905,7 @@ class ServeEngine:
             self.caches, self._tok, self._pos = self._splice(
                 self.caches, pc, jnp.asarray(slot_ids), self._tok, self._pos,
                 next_tok, jnp.asarray(lens))
-        first = np.asarray(jax.device_get(next_tok)).reshape(-1)
-        now = time.perf_counter()
-        for i, (s, r) in enumerate(zip(slots, group)):
-            self.slot_req[s] = r
-            self.slot_pos[s] = lens[i]
-            self._slot_gen[s] += 1    # fresh occupant: stale seals invalid
-            tok = int(first[i])
-            r.generated.append(tok)
-            r.first_token_at = now
-            self.stats.admitted += 1
-            self.tracer.instant("req:admit", rid=r.rid, slot=s)
-            if len(r.generated) >= r.max_new_tokens or tok == r.eos_id:
-                self._free(s)     # degenerate: done at prefill
+        return next_tok
 
     def _free(self, slot: int):
         req = self.slot_req[slot]
@@ -931,7 +942,8 @@ class ServeEngine:
         device->host hop) and a mismatch re-fetches from the still-
         resident device array, so a corrupted payload is never applied."""
         tok_dev, reqs, chunk_final, tok_sum = inflight
-        vals = np.asarray(jax.device_get(tok_dev)).reshape(-1)
+        with self.tracer.span("collect:wait", tick=self._tick_no):
+            vals = np.asarray(jax.device_get(tok_dev)).reshape(-1)
         if tok_sum is not None:
             vals = self._verify_payload(tok_dev, vals, tok_sum)
         now = time.perf_counter()
@@ -1080,6 +1092,8 @@ class ServeEngine:
                     self.sched.requeue_front([req])
                 else:
                     req.admitted_at = time.perf_counter()
+                    self.tracer.record("req:queued", req.submitted_at,
+                                       req.admitted_at, id=req.rid, slot=free)
                     self.slot_req[free] = req
                     self.slot_pos[free] = 0
                     self._slot_gen[free] += 1
@@ -1171,15 +1185,21 @@ class ServeEngine:
         escalation converges on :meth:`_evacuate`.
 
         Observability wraps it once more: the whole tick is a ``tick``
-        span with ``plan`` / ``dispatch`` / ``collect`` / ``admit`` (and
-        ``health`` / ``scrub``) child spans — strictly nested, never
-        crossing a tick boundary — and the queue/active-slot gauges are
-        refreshed at tick exit.  With the tracer disabled (the default)
-        every span is the shared no-op context manager, which is the
-        near-zero-overhead contract bench_serve asserts."""
+        span (a profiler step) with ``plan`` / ``dispatch`` / ``collect``
+        / ``admit`` (and ``health`` / ``scrub``) child spans — strictly
+        nested, never crossing a tick boundary.  ``collect:wait`` is the
+        blocking read of the step's tokens inside ``collect``; ``admit``
+        (opened only while requests wait) holds ``admit:prefill`` (batch,
+        prefill and splice dispatch) and ``admit:wait`` (the read of the
+        first tokens) per admitted group, and each admitted request's
+        queue wait is recorded as a ``req:queued`` span under its rid.
+        The queue/active-slot gauges are refreshed at tick exit.  With the
+        tracer disabled (the default) every span is the shared no-op
+        context manager, which is the near-zero-overhead contract
+        bench_serve asserts."""
         self._tick_no += 1
         t = self._tick_no
-        with self.tracer.span("tick", tick=t):
+        with self.tracer.step("tick", t, tick=t):
             busy = self._tick_body(t)
         self._g_queue.set(self._backlog())
         self._g_active.set(sum(self._decoding(s)
@@ -1231,13 +1251,14 @@ class ServeEngine:
             with self.tracer.span("scrub", tick=t):
                 self._scrub(t)
 
+        if self.scheduler:
+            return (dispatched is not None or processed
+                    or self._backlog() > 0)
         admitted = 0
-        if not self.scheduler:
+        if self.queue:
             with self.tracer.span("admit", tick=t):
                 admitted = self._admit_batch()
-            return dispatched is not None or processed or admitted > 0
-        return (dispatched is not None or processed
-                or self._backlog() > 0)
+        return dispatched is not None or processed or admitted > 0
 
     # -- fault handling -------------------------------------------------------
 

@@ -42,8 +42,9 @@ from repro.serve import kvcache
 
 def greedy_sample(logits: jax.Array) -> jax.Array:
     """logits [B,1,V] (possibly vocab-sharded) -> next token [B] int32."""
-    return jnp.argmax(logits[:, -1].astype(jnp.float32), axis=-1) \
-        .astype(jnp.int32)
+    with jax.named_scope("head"):
+        return jnp.argmax(logits[:, -1].astype(jnp.float32), axis=-1) \
+            .astype(jnp.int32)
 
 
 def temperature_sample(logits: jax.Array, key: jax.Array,
@@ -124,7 +125,7 @@ def make_prefill_step(cfg: ModelConfig, plan: Plan, mesh, *,
     caps = capabilities(cfg)
 
     def prefill(params, batch):
-        with activation_sharding(rules):
+        with activation_sharding(rules), jax.named_scope("prefill"):
             lengths = batch.get("lengths")
             if lengths is None:
                 logits, caches = model_prefill(params, batch, cfg, capacity,
@@ -163,7 +164,7 @@ def make_decode_step(cfg: ModelConfig, plan: Plan, mesh, *,
     rules["kernel_partition"] = partition
 
     def decode(params, token, caches, pos):
-        with activation_sharding(rules):
+        with activation_sharding(rules), jax.named_scope("decode"):
             logits, caches = model_decode_step(params, token, caches, cfg,
                                                pos=pos)
             nxt = greedy_sample(logits)
@@ -197,7 +198,7 @@ def make_paged_decode_step(cfg: ModelConfig, plan: Plan, mesh, *,
     rules["kernel_partition"] = partition
 
     def decode(params, token, caches, pos, block_table, write_bids):
-        with activation_sharding(rules):
+        with activation_sharding(rules), jax.named_scope("decode"):
             logits, caches = model_paged_decode_step(
                 params, token, caches, cfg, pos=pos,
                 block_table=block_table, write_bids=write_bids)
@@ -234,7 +235,7 @@ def make_mixed_step(cfg: ModelConfig, plan: Plan, mesh, *,
 
     def mixed(params, token, caches, pos, c_tok, c_pos, c_slot, c_reset,
               c_last):
-        with activation_sharding(rules):
+        with activation_sharding(rules), jax.named_scope("mixed"):
             logits, caches = model_decode_step(params, token, caches, cfg,
                                                pos=pos)
             nxt = greedy_sample(logits)
@@ -280,7 +281,7 @@ def make_paged_mixed_step(cfg: ModelConfig, plan: Plan, mesh, *,
 
     def mixed(params, token, caches, pos, block_table, write_bids,
               c_tok, c_pos, c_table, c_bids, c_last):
-        with activation_sharding(rules):
+        with activation_sharding(rules), jax.named_scope("mixed"):
             logits, caches = model_paged_decode_step(
                 params, token, caches, cfg, pos=pos,
                 block_table=block_table, write_bids=write_bids)
