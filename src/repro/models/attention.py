@@ -315,18 +315,25 @@ def attention_decode(x: jax.Array, params: dict, cfg: ModelConfig, *,
                      k_cache: jax.Array, v_cache: jax.Array,
                      kv_positions: jax.Array, pos: jax.Array,
                      write_idx: Optional[jax.Array] = None,
+                     layer: Optional[jax.Array] = None,
                      cross: bool = False):
     """One-token decode against a KV cache.
 
-    x [B,1,D]; caches [B,T,KV,Dh] (grouped heads; T may be sharded —
-    context-parallel decode); kv_positions [B,T] (int32; ring-buffer aware —
-    empty slots carry -1); pos [B] absolute position of the new token;
-    write_idx [B] cache slot to write (pos % window for SWA ring buffers).
-    The new K/V entry is inserted *before* attending so the token sees
-    itself.
+    x [B,1,D]; pos [B] absolute position of the new token.
 
-    Returns (y [B,1,D], k_cache', v_cache', kv_positions').
-    For ``cross=True`` the cache is static (encoder memory): no write.
+    Self-attention: the caches are the layer group's stacked
+    [L,B,T,KV,Dh] (grouped heads; T may be sharded — context-parallel
+    decode) and kv_positions [L,B,T] (int32; ring-buffer aware — empty
+    slots carry -1); ``layer`` is this layer's index into them and
+    write_idx [B] the cache slot to write (pos % window for SWA ring
+    buffers).  The new K/V entry is written at [layer, b, write_idx]
+    *before* attending so the token sees itself; the write touches one row
+    per slot, in place when the stacked arrays are the decode scan's carry.
+
+    Cross-attention (``cross=True``): the caches are this layer's own
+    [B,T,KV,Dh] encoder memory, which is static: no write, no ``layer``.
+
+    Returns (y [B,1,D], k_cache', v_cache', kv_positions'), shaped as given.
     """
     B, _, D = x.shape
     H, KV, Dh = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
@@ -335,6 +342,7 @@ def attention_decode(x: jax.Array, params: dict, cfg: ModelConfig, *,
     q = jnp.einsum("bsd,dhk->bshk", x, params["wq"].astype(x.dtype))
     if cfg.qk_norm and "q_norm" in params:
         q = rmsnorm(q, params["q_norm"], cfg.norm_eps)
+    k_l, v_l, p_l = k_cache, v_cache, kv_positions
     if not cross:
         if cfg.use_rope:
             q = apply_rope(q, pos[:, None], cfg.rope_theta)
@@ -350,13 +358,14 @@ def attention_decode(x: jax.Array, params: dict, cfg: ModelConfig, *,
             write_idx = pos
         b = jnp.arange(B)
         with jax.named_scope("kv_update"):
-            k_cache = k_cache.at[b, write_idx].set(k_new[:, 0])
-            v_cache = v_cache.at[b, write_idx].set(v_new[:, 0])
-            kv_positions = kv_positions.at[b, write_idx].set(pos)
+            k_cache = k_cache.at[layer, b, write_idx].set(k_new[:, 0])
+            v_cache = v_cache.at[layer, b, write_idx].set(v_new[:, 0])
+            kv_positions = kv_positions.at[layer, b, write_idx].set(pos)
+        k_l, v_l, p_l = k_cache[layer], v_cache[layer], kv_positions[layer]
 
     rules = current_rules() or {}
     if (rules.get("decode_attn_impl") == "pallas"
-            and pallas_decode_supported(cfg, k_cache.shape[1], cross=cross)):
+            and pallas_decode_supported(cfg, k_l.shape[1], cross=cross)):
         # Flash-decode Pallas kernel: online softmax over kv blocks, never
         # materializes the [T] score vector in HBM.  Positional masking
         # (incl. the SWA ring buffer) matches the jnp path below.  The
@@ -364,14 +373,12 @@ def attention_decode(x: jax.Array, params: dict, cfg: ModelConfig, *,
         # over 'model' when they divide (replicated dispatch otherwise).
         from repro.kernels import partition as kernel_partition
         out = kernel_partition.decode_attention(
-            q[:, 0], k_cache, v_cache, kv_positions, pos,
-            window=cfg.sliding_window or 0)
+            q[:, 0], k_l, v_l, p_l, pos, window=cfg.sliding_window or 0)
         y = jnp.einsum("bshk,hkd->bsd", out[:, None],
                        params["wo"].astype(x.dtype))
         return y, k_cache, v_cache, kv_positions
 
-    out = _jnp_decode_attend(q, k_cache, v_cache, kv_positions, pos, cfg,
-                             cross=cross)
+    out = _jnp_decode_attend(q, k_l, v_l, p_l, pos, cfg, cross=cross)
     y = jnp.einsum("bshk,hkd->bsd", out, params["wo"].astype(x.dtype))
     return y, k_cache, v_cache, kv_positions
 

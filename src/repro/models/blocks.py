@@ -207,13 +207,15 @@ def run_groups(x, group_params: list, cfg: ModelConfig, *, positions,
 
 
 def block_decode(kind: str, x, p, cfg: ModelConfig, cache: dict, *,
-                 pos, write_idx, memory=None, paged=None):
+                 pos, write_idx, layer=None, memory=None, paged=None):
     """One block, one token. Returns (x, new_cache).
 
     ``paged`` = {"block_table": [B,M], "write_bids": [B]} switches the
     attention cache to the pooled paged layout (cache leaves are then the
     per-layer block pools); dense/ring layouts take the ``write_idx``
-    path."""
+    path, where the self-attention ``k``/``v``/``pos`` leaves are the
+    group's whole stacked arrays and ``layer`` indexes them (see
+    :func:`run_groups_decode`)."""
     h = rmsnorm(x, p["norm1"], cfg.norm_eps)
     if kind.startswith("attn"):
         with jax.named_scope("attn"):
@@ -237,7 +239,7 @@ def block_decode(kind: str, x, p, cfg: ModelConfig, cache: dict, *,
                 a, kc, vc, kp = attention_decode(
                     h, p["attn"], cfg, k_cache=cache["k"],
                     v_cache=cache["v"], kv_positions=cache["pos"], pos=pos,
-                    write_idx=write_idx)
+                    write_idx=write_idx, layer=layer)
             cache = dict(cache, k=kc, v=vc, pos=kp)
             x = x + a
             if kind == "attn_cross":
@@ -281,32 +283,55 @@ def block_decode(kind: str, x, p, cfg: ModelConfig, cache: dict, *,
     return x, cache
 
 
+def _carried_leaves(kind: str, paged) -> tuple:
+    """The cache leaves of one block that ride the decode scan as its carry:
+    a dense/ring self-attention cache, written in place at the layer's
+    index.  Paged pools, recurrent states and cross-attention memory are
+    scanned per layer instead."""
+    if paged is None and kind.startswith("attn"):
+        return ("k", "v", "pos")
+    return ()
+
+
 def run_groups_decode(x, group_params: list, caches: list, cfg: ModelConfig, *,
                       pos, write_idx, paged=None):
     """One-token step through all groups; caches updated functionally.
+
+    A dense/ring self-attention cache (``k``, ``v``, ``pos``, each stacked
+    ``[L, ...]``) is the scan's carry: each layer writes its new row at
+    ``[layer, b, write_idx]`` and reads its own slice, so under donation
+    the stacked arrays are updated in place and never copied.  Every other
+    leaf (recurrent states, cross-attention memory, paged pools) is scanned
+    per layer as ``xs`` and gathered back as ``ys``.
 
     ``paged`` (block table + per-tick write plan) applies to every
     attention layer — one table serves all layers, the pool-per-layer
     paged-KV contract."""
     new_caches = []
     for group, gp, gc in zip(cfg.groups, group_params, caches):
+        keys = {f"sub{j}": _carried_leaves(kind, paged)
+                for j, kind in enumerate(group.pattern)}
+        carried = {s: {k: gc[s][k] for k in ks} for s, ks in keys.items()}
+        scanned = {s: {k: a for k, a in gc[s].items() if k not in ks}
+                   for s, ks in keys.items()}
 
-        def body(xx, scanned):
-            layer_p, layer_c = scanned
+        def body(carry, xs):
+            xx, stacked = carry
+            layer_p, layer_c, layer = xs
+            stacked, layer_c = dict(stacked), dict(layer_c)
             for j, kind in enumerate(group.pattern):
-                wi = write_idx.get(kind_cache_key(kind)) if isinstance(write_idx, dict) else write_idx
-                xx, layer_c[f"sub{j}"] = block_decode(
-                    kind, xx, layer_p[f"sub{j}"], cfg, layer_c[f"sub{j}"],
-                    pos=pos, write_idx=wi, paged=paged)
-            return xx, layer_c
+                s = f"sub{j}"
+                xx, c = block_decode(
+                    kind, xx, layer_p[s], cfg, {**layer_c[s], **stacked[s]},
+                    pos=pos, write_idx=write_idx, layer=layer, paged=paged)
+                stacked[s] = {k: c[k] for k in keys[s]}
+                layer_c[s] = {k: a for k, a in c.items() if k not in keys[s]}
+            return (xx, stacked), layer_c
 
-        x, nc = jax.lax.scan(body, x, (gp, gc))
-        new_caches.append(nc)
+        (x, carried), nc = jax.lax.scan(
+            body, (x, carried), (gp, scanned, jnp.arange(group.repeats)))
+        new_caches.append({s: {**nc[s], **carried[s]} for s in keys})
     return x, new_caches
-
-
-def kind_cache_key(kind: str) -> str:
-    return "attn" if kind.startswith("attn") else "ssm"
 
 
 # ---------------------------------------------------------------------------

@@ -1,9 +1,11 @@
 """Decode-state management: KV caches (dense + SWA ring-buffer), SSM states.
 
 Cache layout mirrors the layer-group structure: one pytree per group, every
-leaf stacked along a leading "layers" axis of length group.repeats, so
-``run_groups_decode`` can thread it through the same ``lax.scan`` as the
-parameters.
+leaf stacked along a leading "layers" axis of length group.repeats.
+``run_groups_decode`` carries a group's stacked self-attention ``k``/``v``/
+``pos`` through its ``lax.scan`` and writes each token's row in place at
+``[layer, slot, write_idx]``; the other leaves (recurrent states, the
+cross-attention memory) are scanned per layer alongside the parameters.
 
 For sliding-window archs (mixtral) the attention cache is a ring buffer of
 ``window`` slots — decode at 500k context holds 4096 entries, not 500k

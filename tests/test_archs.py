@@ -7,9 +7,12 @@ import numpy as np
 import pytest
 
 from repro.configs import get_config, get_smoke_config, list_archs
+from repro.models import blocks, encdec, lm
 from repro.models.registry import (model_decode_step, model_loss,
                                    model_prefill, model_specs)
 from repro.models.common import count_params, init_params
+from repro.models.sharding import activation_sharding
+from repro.serve import kvcache
 from repro.optim.adamw import AdamWConfig, adamw_init, adamw_update
 
 ARCHS = list_archs()
@@ -115,3 +118,84 @@ def test_prefill_decode_consistency(arch):
         - logits_ref[:, -1].astype(jnp.float32))))
     tol = DECODE_TOL.get(arch, 1e-3)
     assert err <= tol, f"{arch}: decode err {err} > {tol}"
+
+
+def _per_layer_decode(x, group_params, caches, cfg, *, pos, write_idx,
+                      paged=None):
+    """Reference for ``blocks.run_groups_decode``: a plain Python loop over
+    the layers, no scan.  Each layer decodes against its own slice of every
+    cache leaf (its self-attention K/V/pos as a one-layer stack), and the
+    slice is written back whole.  Optimisation barriers bound each layer as
+    the scan body does, so XLA fuses (and rounds) within a layer alike."""
+    assert paged is None
+    new_caches = []
+    for group, gp, gc in zip(cfg.groups, group_params, caches):
+        for i in range(group.repeats):
+            layer_p = jax.tree.map(lambda a: a[i], gp)
+            own = {}
+            for j, kind in enumerate(group.pattern):
+                s, attn = f"sub{j}", kind.startswith("attn")
+                own[s] = {k: a[i:i + 1] if attn and k in ("k", "v", "pos")
+                          else a[i] for k, a in gc[s].items()}
+            x, own = jax.lax.optimization_barrier((x, own))
+            for j, kind in enumerate(group.pattern):
+                s = f"sub{j}"
+                x, own[s] = blocks.block_decode(
+                    kind, x, layer_p[s], cfg, own[s], pos=pos,
+                    write_idx=write_idx, layer=0)
+            x, own = jax.lax.optimization_barrier((x, own))
+            gc = {s: {k: gc[s][k].at[i].set(     # [0]: a one-layer stack
+                          a[0] if a.ndim == gc[s][k].ndim else a)
+                      for k, a in own[s].items()}
+                  for s in gc}
+        new_caches.append(gc)
+    return x, new_caches
+
+
+@pytest.mark.parametrize("impl", ["ref", "pallas"])
+@pytest.mark.parametrize("arch", ["qwen3-4b", "mixtral-8x7b", "jamba-v0.1-52b",
+                                  "whisper-tiny", "xlstm-125m"])
+def test_decode_scan_matches_per_layer_loop(arch, impl, monkeypatch):
+    """The decode scan, which carries the stacked self-attention caches and
+    writes each new row in place, against a per-layer loop: 12 greedy steps
+    from three slots at different positions (the SWA ring wraps for
+    mixtral), bitwise equal tokens and caches.  Dense, ring, hybrid (mamba
+    states scanned), enc-dec (cross caches scanned) and xLSTM (all scanned);
+    ``pallas`` is the flash-decode kernel in interpret mode."""
+    cfg = get_smoke_config(arch)
+    params = init_params(model_specs(cfg), jax.random.PRNGKey(0))
+    B, S = 3, 10
+    toks = jax.random.randint(jax.random.PRNGKey(1), (B, S), 0,
+                              cfg.vocab_size)
+    batch = {"tokens": toks}
+    if cfg.encoder:
+        batch["audio_embeds"] = jax.random.normal(
+            jax.random.PRNGKey(2), (B, 16, cfg.d_model), jnp.float32)
+    _, caches = model_prefill(params, batch, cfg, capacity=24)
+    lengths = jnp.array([3, 6, 10], jnp.int32)
+    caches = kvcache.mask_prefill_pos(cfg, caches, lengths)
+
+    def run():
+        step = jax.jit(lambda p, t, c, q: model_decode_step(p, t, c, cfg,
+                                                            pos=q))
+        c, pos = caches, lengths
+        tok = toks[jnp.arange(B), lengths - 1][:, None]
+        out = []
+        with activation_sharding({"decode_attn_impl": impl}):
+            for _ in range(12):
+                logits, c = step(params, tok, c, pos)
+                tok = jnp.argmax(logits[:, -1], -1).astype(jnp.int32)[:, None]
+                out.append(np.asarray(tok))
+                pos = pos + 1
+        return np.concatenate(out, axis=1), c
+
+    tokens, got = run()
+    for mod in (lm, encdec):
+        monkeypatch.setattr(mod, "run_groups_decode", _per_layer_decode)
+    ref_tokens, want = run()
+    np.testing.assert_array_equal(tokens, ref_tokens)
+    assert jax.tree.structure(got) == jax.tree.structure(want)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert a.shape == b.shape and a.dtype == b.dtype
+        np.testing.assert_array_equal(np.asarray(a.astype(jnp.float32)),
+                                      np.asarray(b.astype(jnp.float32)))

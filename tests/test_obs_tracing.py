@@ -294,10 +294,14 @@ def test_decoder_layer_matmuls_sit_in_attn_or_ffn(path):
         def f(x, p, cache):
             pos = jnp.zeros((B,), jnp.int32)
             return block_decode("attn_cross", x, p, cfg, cache, pos=pos,
-                                write_idx=pos)
+                                write_idx=pos, layer=0)
+
+        def one_layer_stack():      # the self-attention leaves as carried
+            c = _kind_cache("attn_cross", cfg, B, 16, T)
+            return {k: a[None] if k in ("k", "v", "pos") else a
+                    for k, a in c.items()}
         args = (jax.ShapeDtypeStruct((B, 1, 64), cfg.dtype), p,
-                jax.eval_shape(lambda: _kind_cache("attn_cross", cfg, B, 16,
-                                                   T)))
+                jax.eval_shape(one_layer_stack))
     txt = jax.jit(f).lower(*args).as_text(debug_info=True)
     dots = re.findall(r'loc\("jit\(f\)/([^"]*)/dot_general"', txt)
     scopes = {d.split("/")[0] for d in dots}

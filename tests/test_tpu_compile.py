@@ -14,17 +14,25 @@ pytest workers the worker given this file is the one that loads it.
 from __future__ import annotations
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.configs import get_config
+from repro.core.topology import make_plan
 from repro.kernels import decode_attention as da
 from repro.kernels import flash_attention as fa
 from repro.kernels import fused_ffn as ffn
+from repro.kernels import ops
 from repro.kernels import paged_attention as pa
 from repro.kernels import quant
+from repro.models.common import abstract_params
+from repro.models.registry import model_specs
+from repro.serve import kvcache
+from repro.serve.steps import make_decode_step
 
 H, KV, DH, D_MODEL, D_FF = 32, 8, 128, 2560, 9728
 BF16, F32, I32, I8 = jnp.bfloat16, jnp.float32, jnp.int32, jnp.int8
@@ -178,3 +186,61 @@ def test_kernel_compiles_for_v5e(shape, case):
     fn, args = CASES[case](shape)
     _compile(fn, *args)
 
+
+
+# One HLO instruction: ``%name = dtype[dims]{layout} opcode(``.
+_INSTR = re.compile(r"^\s*(?:ROOT )?%(\S+) = \w+\[([\d,]*)\]\S* (\S+?)\(")
+
+
+def test_dense_decode_step_updates_kv_cache_in_place(shape, monkeypatch):
+    """The whole qwen3-4b decode step as the engine compiles it (caches
+    donated, B=4, T=4096, bf16, Pallas attention and FFN): each layer's
+    new K/V row is scattered into the stacked cache the layer scan
+    carries.  No whole-cache copy, no per-layer write-back and no
+    scatter into a per-layer copy may appear, and the step's scratch
+    memory stays far below one K leaf (a second copy of the cache would
+    not)."""
+    monkeypatch.setenv("REPRO_FFN_IMPL", "pallas")
+    cfg = get_config("qwen3-4b")
+    B, T = 4, 4096
+    L = cfg.groups[0].repeats
+
+    def abstract(tree):
+        return jax.tree.map(lambda a: shape(a.shape, a.dtype), tree)
+
+    params = abstract(abstract_params(model_specs(cfg), BF16))
+    caches = abstract(kvcache.abstract_cache(cfg, B, T))
+    step = make_decode_step(cfg, make_plan(cfg, {}, shape_kind="decode",
+                                           seq_len=T), None,
+                            attn_impl="pallas", advance_pos=True)
+    was = ops._INTERPRET
+    ops.set_interpret_mode(False)
+    try:
+        compiled = jax.jit(step, donate_argnums=(2,)).lower(
+            params, shape((B, 1), I32), caches, shape((B,), I32)).compile()
+    finally:
+        ops.set_interpret_mode(was)
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+
+    k_leaf = caches[0]["sub0"]["k"]
+    assert k_leaf.shape == (L, B, T, KV, DH)
+    k_bytes = k_leaf.size * jnp.dtype(k_leaf.dtype).itemsize
+    assert compiled.memory_analysis().temp_size_in_bytes < k_bytes
+
+    stacked = ",".join(map(str, (L, B, T, KV, DH)))
+    per_layer = ",".join(map(str, (B, T, KV, DH)))
+    moves, writes = [], []
+    for line in text.splitlines():
+        m = _INSTR.match(line)
+        if m is None:
+            continue
+        name, dims, op = m.groups()
+        if dims not in (stacked, per_layer):
+            continue
+        if any(w in name or w == op for w in ("copy", "dynamic-update-slice")):
+            moves.append(name)
+        if op == "scatter":
+            writes.append((name, dims))
+    assert not moves, f"whole K/V cache or layer slice moved: {moves}"
+    assert writes and all(d == stacked for _, d in writes), writes
