@@ -1,14 +1,16 @@
 """The comparison that decides ``correct`` for a served model.
 
 After the window has closed, a sample of the requests drawn from the seed
-(the one with the most served tokens always in it, then others until the
-sample holds ``min_tokens`` served tokens) is run through the reference
-once, over each prompt followed by its served tokens.  At the position that
-produced each served token, the gap is how far the served token's logit
-lies below the reference's best logit there.  Greedy decoding with no
-error gives 0; a near-tie that rounding flipped gives a small gap.  The
-widest gap, the mean gap and the share of positions with a gap are read
-over the sample; the cell's limits file names those compared.
+(the one with the most served tokens and every one that held a slot at
+the window's close always in it, then others until the sample holds
+``min_tokens`` served tokens) is run through the reference once, over
+each prompt followed by its served tokens: layer by layer, all the
+sample's sequences through one layer before the next.  At the position
+that produced each served token, the gap is how far the served token's
+logit lies below the reference's best logit there.  Greedy decoding with
+no error gives 0; a near-tie that rounding flipped gives a small gap.
+The widest gap, the mean gap and the share of positions with a gap are
+read over the sample; the cell's limits file names those compared.
 
 The control reads the same positions with the int8 reference in the
 program's place: the gap of the token it puts first.
@@ -18,19 +20,25 @@ from __future__ import annotations
 import numpy as np
 
 from harness.reference import Reference
+from harness.spec import load_model
 
 
-def sample(requests: list, seed: int, min_tokens: int) -> list:
-    """Requests with served tokens: the longest, then others in an order
-    drawn from the seed, until ``min_tokens`` served tokens are held."""
+def sample(requests: list, seed: int, min_tokens: int, held=()) -> list:
+    """Requests with served tokens: the longest, every one of ``held``
+    (those in a slot when the window closed, so that each slot busy then
+    is compared), then others in an order drawn from the seed, until
+    ``min_tokens`` served tokens are held."""
     served = [r for r in requests if r.generated]
     if not served:
         return []
     longest = max(served, key=lambda r: (len(r.generated), -r.rid))
-    rest = [r for r in served if r is not longest]
+    out = [longest] + sorted((r for r in held
+                              if r.generated and r is not longest),
+                             key=lambda r: r.rid)
+    rest = [r for r in served if all(r is not o for o in out)]
     order = np.random.default_rng([seed & 0xFFFFFFFF, seed >> 32, 4]
                                   ).permutation(len(rest))
-    out, n = [longest], len(longest.generated)
+    n = sum(len(r.generated) for r in out)
     for i in order:
         if n >= min_tokens:
             break
@@ -52,21 +60,25 @@ def gaps(params, config: dict, requests: list, *, control: bool = False,
     """Per request, the gap at every served position: under "program",
     of the served token; with ``control``, also under "control", of the
     control's first choice at the same positions."""
-    ref = Reference(params, config, device=device)
-    ctl = (Reference(params, config, device=device, control=True)
-           if control else None)
     out = {"program": []}
-    if ctl is not None:
+    if control:
         out["control"] = []
-    for r in requests:
-        served = np.asarray(r.generated, np.int32)
-        seq, rows = _positions(r.prompt, served)
-        want = np.asarray(ref.logits(ref.hidden(seq), rows))
+    if not requests:
+        return out
+    ref = Reference(params, config, load_model(config), device=device)
+    served = [np.asarray(r.generated, np.int32) for r in requests]
+    pos = [_positions(r.prompt, s) for r, s in zip(requests, served)]
+    hidden = ref.hidden([seq for seq, _ in pos],
+                        (False, True) if control else (False,))
+    head = ref.head()
+    for i, (tok, (_, rows)) in enumerate(zip(served, pos)):
+        want = np.asarray(ref.logits(hidden[False][i], rows, head))
         best = want.max(-1)
         at = np.arange(len(rows))
-        out["program"].append(best - want[at, served])
-        if ctl is not None:
-            pick = np.asarray(ctl.logits(ctl.hidden(seq), rows)).argmax(-1)
+        out["program"].append(best - want[at, tok])
+        if control:
+            pick = np.asarray(ref.logits(hidden[True][i], rows, head,
+                                         control=True)).argmax(-1)
             out["control"].append(best - want[at, pick])
     return out
 

@@ -5,8 +5,9 @@ call, max(flops / peak flops, HBM bytes / HBM bandwidth, VMEM bytes / VMEM
 bandwidth), over the time the call took.  Bytes are split by where the
 compiler placed each operand (``hlo.Buffer.vmem``).
 
-The model-level counts (``ModelCost``) are the operations that the useful
-tokens need: no padding rows, no padded prompt columns, no slot that holds
+The model-level counts are each model type's own (``cost`` in
+``bench/models/<model_type>.py``): the operations that the useful tokens
+need, with no padding rows, no padded prompt columns, no slot that holds
 no request.
 """
 from __future__ import annotations
@@ -68,48 +69,3 @@ def decode_attention_live(op: Op, contexts) -> Work:
                 (kv_pos, live * 4), (q, rows * per_row_q),
                 (out, rows * per_row_o)])
     return Work(4.0 * KV * G * D * live, w.hbm_bytes, w.vmem_bytes)
-
-
-@dataclass(frozen=True)
-class ModelCost:
-    """Operations of a dense decoder with GQA attention and a SwiGLU FFN."""
-
-    layers: int
-    d_model: int
-    heads: int
-    kv_heads: int
-    head_dim: int
-    d_ff: int
-    vocab: int
-
-    @classmethod
-    def of(cls, config: dict) -> "ModelCost":
-        return cls(config["num_hidden_layers"], config["hidden_size"],
-                   config["num_attention_heads"],
-                   config["num_key_value_heads"], config["head_dim"],
-                   config["intermediate_size"], config["vocab_size"])
-
-    @property
-    def layer_params(self) -> int:
-        D, H, KV, Dh, F = (self.d_model, self.heads, self.kv_heads,
-                           self.head_dim, self.d_ff)
-        return D * H * Dh + 2 * D * KV * Dh + H * Dh * D + 3 * D * F
-
-    @property
-    def matmul_params(self) -> int:
-        """Parameters every token multiplies: all layers, not the embedding
-        lookup; the output head counts per logit row."""
-        return self.layers * self.layer_params
-
-    def prefill_flops(self, prompt: int) -> float:
-        """One prompt: every layer over every token, causal attention over
-        P (P + 1) / 2 query-key pairs, and one row of logits."""
-        P = prompt
-        attn = 2.0 * self.layers * self.heads * self.head_dim * P * (P + 1)
-        return (2.0 * self.matmul_params * P + attn
-                + 2.0 * self.d_model * self.vocab)
-
-    def decode_flops(self, context: int) -> float:
-        """One generated token attending to ``context`` cache entries."""
-        attn = 4.0 * self.layers * self.heads * self.head_dim * context
-        return 2.0 * self.matmul_params + attn + 2.0 * self.d_model * self.vocab

@@ -7,9 +7,12 @@ was due: in a closed loop, the moment its client's previous reply
 completed (plus the think time); in an open loop, its arrival time.
 
 A closed loop is primed before its window opens: each client's first
-request is submitted and served to its first token, so that the window
-sees every slot busy, as a loop that has run for a while does, and not the
-opening admissions of an empty engine.
+request is submitted and served until it has its first decoded token, so
+that the window sees every slot busy, as a loop that has run for a while
+does, and not the opening admissions of an empty engine, nor the gaps
+that span them (the first requests' prompts are admitted in groups, one
+prefill after another, and a request's first gap waits for the groups
+after its own).
 """
 from __future__ import annotations
 
@@ -21,7 +24,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from harness import stats
-from harness.spec import Cell, model_config
+from harness.spec import Cell, load_model
 from harness.traffic import Traffic, warm_shapes
 from harness.weights import make_params
 
@@ -46,6 +49,7 @@ class Window:
     compiles: int = 0
     ticks: int = 0
     drained_at: float = 0.0
+    held: list = field(default_factory=list)   # in a slot at the close
 
 
 class CompileCounter:
@@ -72,7 +76,8 @@ def build(cell: Cell, seed: int, *, wrap_runtime=None):
     """The Runtime with the benchmark's weights, and its engine."""
     from repro.launch.mesh import mesh_from_spec
     from repro.runtime import Runtime
-    cfg = model_config(cell.config)
+    model = load_model(cell.config)
+    cfg = model.model_config(cell.config)
     tp = int(cell.config["deployment"]["tensor_parallel"])
     eng_s = cell.traffic["engine"]
     mesh = mesh_from_spec(str(tp)) if tp > 1 else None
@@ -80,7 +85,7 @@ def build(cell: Cell, seed: int, *, wrap_runtime=None):
                         capacity=eng_s["capacity"],
                         kv_layout=eng_s.get("kv_layout", "dense"),
                         param_dtype=jnp.bfloat16)
-    rt.params = make_params(rt.specs, cfg.d_model, seed,
+    rt.params = make_params(rt.specs, cfg.d_model, seed, model,
                             shardings=rt.param_shardings)
     jax.block_until_ready(rt.params)
     if wrap_runtime is not None:
@@ -144,7 +149,7 @@ def run_window(eng, traffic: Traffic, seconds: float, counter: CompileCounter,
     if closed:
         submit_due([time.perf_counter()] * clients, time.perf_counter())
         limit = time.perf_counter() + DRAIN_S
-        while (any(not r.first_token_at for r in live.values())
+        while (any(not (r.token_times or r.done) for r in live.values())
                and time.perf_counter() < limit):
             eng.tick()
         jax.block_until_ready(eng.caches)
@@ -186,6 +191,7 @@ def run_window(eng, traffic: Traffic, seconds: float, counter: CompileCounter,
             time.sleep(max(0.0, min(arrivals[nxt] - time.perf_counter(),
                                     0.001)))
     w.compiles = counter.n - start_compiles
+    w.held = [r for r in live.values() if r.first_token_at and not r.done]
     if on_stop is not None:
         on_stop()
     limit = time.perf_counter() + DRAIN_S

@@ -11,15 +11,20 @@ is found by name:
   the engine's settings, and the size of the correctness sample;
 * ``bench/limits/<cell>.json``     the cell's correctness limits, by the
   name of the number each holds (PERF.md gives the readings behind them);
-* ``bench/metrics/<metric>.py``    one per-layer metric's reader.
+* ``bench/metrics/<metric>.py``    one per-layer metric's reader;
+* ``bench/models/<model_type>.py`` one model type: the configuration's
+  ``model_type`` names it (``load_model``).
 
-A later cell, mix, configuration or metric is new files and new entries;
-no file here changes.
+A later cell, mix, configuration, metric or model type is new files and
+new entries; no file here changes.
 """
 from __future__ import annotations
 
+import functools
 import importlib.util
 import json
+import sys
+import zlib
 from dataclasses import dataclass
 from pathlib import Path
 
@@ -82,26 +87,25 @@ def load_reader(metric: str, root: Path = ROOT):
     return mod.read
 
 
-def model_config(config: dict):
-    """The program's ``ModelConfig`` for a configuration file's published
-    sizes (``model_type`` "qwen3": GQA with per-head RMSNorm on q and k,
-    RoPE, SwiGLU, RMSNorm before each sub-layer)."""
-    from repro.models.common import LayerGroup, ModelConfig
-    if config.get("model_type") != "qwen3":
-        raise ValueError(f"model_type {config.get('model_type')!r} has no "
-                         f"mapping onto the program's ModelConfig")
-    if config.get("hidden_act") != "silu" or config.get("attention_bias"):
-        raise ValueError("qwen3 mapping expects silu and no attention bias")
-    L = int(config["num_hidden_layers"])
-    return ModelConfig(
-        name=config["name"], family="dense", num_layers=L,
-        d_model=config["hidden_size"],
-        num_heads=config["num_attention_heads"],
-        num_kv_heads=config["num_key_value_heads"],
-        head_dim=config["head_dim"], d_ff=config["intermediate_size"],
-        vocab_size=config["vocab_size"],
-        groups=(LayerGroup(("attn",), L),), mlp_act="silu",
-        rope_theta=float(config["rope_theta"]), qk_norm=True,
-        norm_eps=float(config["rms_norm_eps"]),
-        tie_embeddings=bool(config["tie_word_embeddings"]),
-        attn_mode="heads")
+@functools.lru_cache(maxsize=None)
+def _module(path: str):
+    """The module at ``path``, executed once per process, under a name of
+    its own in ``sys.modules`` (dataclasses look their module up there)."""
+    name = f"bench_model_{Path(path).stem}_{zlib.crc32(path.encode()):08x}"
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    sys.modules[name] = mod
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def load_model(config: dict, root: Path = ROOT):
+    """The module ``bench/models/<model_type>.py`` of a configuration: the
+    model type's mapping onto the program (``model_config``), the weight
+    laws of its layers (``leaf_law``), its float32 reference layer
+    (``layer_weights``, ``layer``) and its operation counts (``cost``)."""
+    path = root / "bench" / "models" / f"{config.get('model_type')}.py"
+    if not path.is_file():
+        raise FileNotFoundError(
+            f"model_type {config.get('model_type')!r} has no module: {path}")
+    return _module(str(path))

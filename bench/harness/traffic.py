@@ -9,13 +9,17 @@ A mix (``bench/traffic/<mix>.json``) states:
 * ``prompt_len`` / ``output_len``: ``{"dist": "uniform"|"lognormal",
   "min", "max"[, "median", "sigma"]}``;
 * ``strata``: how many lengths make one cycle.  The lengths are the
-  distribution's quantiles at (k + 1/2) / strata, so every seed serves the
-  same set of sizes; the seed only orders them and draws the token ids;
+  distribution's quantiles at (k + 1/2) / strata, each cycle in an order
+  that is the same for every seed, as are an open loop's arrivals: a
+  window sees a few requests of a cycle, and which ones decides its work
+  (how many end and are admitted inside it), so the seed must not.  The
+  seed draws the token ids;
 * ``engine``: the serving engine's settings (slots, capacity, kv layout,
   max_admit);
 * ``check``: how many served tokens the correctness sample holds.
 
-Request ``i`` of a seed is the same on every run of that seed.
+Request ``i`` of a seed is the same on every run of that seed, and has
+the same sizes under every seed.
 """
 from __future__ import annotations
 
@@ -50,6 +54,9 @@ def _rng(seed: int, *tags: int) -> np.random.Generator:
     return np.random.default_rng([seed & 0xFFFFFFFF, seed >> 32, *tags])
 
 
+ORDER = 0       # the key of the sizes' order and of the arrivals, any seed
+
+
 class Traffic:
     def __init__(self, mix: dict, vocab: int, seed: int):
         self.mix, self.vocab, self.seed = mix, vocab, seed
@@ -69,7 +76,7 @@ class Traffic:
     def _perm(self, cycle: int, which: int) -> np.ndarray:
         key = (cycle, which)
         if key not in self._perms:
-            self._perms[key] = _rng(self.seed, 1, cycle, which).permutation(
+            self._perms[key] = _rng(ORDER, 1, cycle, which).permutation(
                 self.n)
         return self._perms[key]
 
@@ -86,7 +93,7 @@ class Traffic:
         rate = float(self.mix["rate"])
         cv = float(self.mix.get("burst_cv", 1.0))
         shape = 1.0 / (cv * cv)
-        rng = _rng(self.seed, 3)
+        rng = _rng(ORDER, 3)
         out, t = [], 0.0
         while True:
             t += rng.gamma(shape, 1.0 / (rate * shape))

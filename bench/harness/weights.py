@@ -7,9 +7,11 @@ the order of the tree or on how the leaves are sharded:
 
 * norm scales: 1 + 0.1 * N(0, 1), so that a norm applied with the wrong
   scale shows in the logits;
-* every matrix and embedding table: N(0, 1) / sqrt(fan_in), where fan_in is
-  the width that the matrix contracts (the embedding tables: d_model, so
-  that logits come out near unit scale).
+* the embedding tables: N(0, 1) / sqrt(d_model), so that logits come out
+  near unit scale;
+* every other leaf: the law of the model type's ``leaf_law``
+  (``bench/models/<model_type>.py``), for a matrix N(0, 1) / sqrt(fan_in),
+  where fan_in is the width that the matrix contracts.
 
 The reference reads these same arrays; nothing of them is made by the
 program.
@@ -41,33 +43,29 @@ def _path_name(path) -> str:
                     for k in path)
 
 
-def leaf_law(name: str, shape: tuple, d_model: int) -> tuple[str, float]:
+def leaf_law(name: str, shape: tuple, d_model: int, model
+             ) -> tuple[str, float]:
     """(kind, std) for the leaf at ``name``: kind "norm" or "normal"."""
     last = name.rsplit("/", 1)[-1]
     if "norm" in last:
         return "norm", NORM_JITTER
     if last in ("embed", "unembed"):
         return "normal", d_model ** -0.5
-    if last in ("wq", "wk", "wv", "wi_gate", "wi_up"):
-        return "normal", shape[-3 if last in ("wq", "wk", "wv") else -2] ** -0.5
-    if last == "wo" and len(shape) == 4:           # attention out [L,H,Dh,D]
-        return "normal", (shape[-3] * shape[-2]) ** -0.5
-    if last == "wo":                               # ffn down [L,F,D]
-        return "normal", shape[-2] ** -0.5
-    raise KeyError(f"no weight law for leaf {name!r} {shape}")
+    return model.leaf_law(name, shape)
 
 
-def make_params(shapes, d_model: int, seed: int, dtype=jnp.bfloat16,
-                shardings=None):
+def make_params(shapes, d_model: int, seed: int, model,
+                dtype=jnp.bfloat16, shardings=None):
     """Every leaf of ``shapes`` (a tree of objects with ``.shape``) drawn
-    from ``seed`` in one jitted program, each leaf born in its sharding."""
+    from ``seed`` in one jitted program, each leaf born in its sharding;
+    ``model`` is the model type's module."""
     leaves, treedef = jax.tree_util.tree_flatten_with_path(
         shapes, is_leaf=lambda x: hasattr(x, "shape"))
     plan = []
     for path, leaf in leaves:
         name = _path_name(path)
         shape = tuple(leaf.shape)
-        kind, std = leaf_law(name, shape, d_model)
+        kind, std = leaf_law(name, shape, d_model, model)
         plan.append((zlib.crc32(name.encode()), shape, kind, std))
 
     def build(lo, hi):

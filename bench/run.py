@@ -34,9 +34,8 @@ sys.path[:0] = [str(ROOT / "src"), str(BENCH)]
 
 from harness import check, stats  # noqa: E402
 from harness import trace as tr  # noqa: E402
-from harness.cost import ModelCost  # noqa: E402
 from harness.peaks import peaks  # noqa: E402
-from harness.spec import load_cell, load_reader  # noqa: E402
+from harness.spec import load_cell, load_model, load_reader  # noqa: E402
 
 TRACE_DIR = ROOT / ".bench_out" / "trace"
 
@@ -168,7 +167,8 @@ def execute(cell, seed: int, seconds: float, trace: bool, *,
         peak = peaks(info["kind"]) if info["platform"] == "tpu" else None
         ctx = {"trace": t, "lo": lo, "hi": hi, "window_s": seconds,
                "window": w, "chips": len(chips), "peak": peak,
-               "config": cell.config, "cost": ModelCost.of(cell.config),
+               "config": cell.config,
+               "cost": load_model(cell.config).cost(cell.config),
                "host_spans": _host_spans(eng.tracer)}
         for m in cell.per_layer:
             v = load_reader(m["name"])(ctx) if chips else None
@@ -190,7 +190,7 @@ def execute(cell, seed: int, seconds: float, trace: bool, *,
     del eng
     gc.collect()
     picked = check.sample([rc.req for rc in w.recs], seed,
-                          int(cell.traffic["check"]["min_tokens"]))
+                          int(cell.traffic["check"]["min_tokens"]), w.held)
     t_ref = time.perf_counter()
     gaps = check.gaps(params, cell.config, picked, control=control)
     numbers = compare(gaps["program"], result["failed"])

@@ -4,6 +4,9 @@ import pytest
 import tiny  # noqa: F401
 from harness import cost, hlo
 from harness.peaks import peaks
+from harness.spec import load_model
+
+QWEN3 = load_model({"model_type": "qwen3"})
 
 FFN = ("%swiglu_ffn.4 = bf16[4,2560]{1,0:T(4,128)(2,1)S(1)} custom-call("
        "bf16[4,2560]{1,0:T(4,128)(2,1)S(1)} %fusion.90, "
@@ -47,7 +50,7 @@ def test_min_seconds_takes_the_binding_resource():
 
 
 def test_model_flops_by_hand():
-    m = cost.ModelCost(layers=2, d_model=64, heads=4, kv_heads=2,
+    m = QWEN3.ModelCost(layers=2, d_model=64, heads=4, kv_heads=2,
                        head_dim=16, d_ff=128, vocab=256)
     per_layer = 64 * 64 + 2 * 64 * 32 + 64 * 64 + 3 * 64 * 128
     assert m.layer_params == per_layer

@@ -18,7 +18,7 @@ SEED = 2**33 + 17
 
 
 def _cell():
-    c = tiny.cell(limit=0.5)
+    c = tiny.cell()
     c.traffic["output_len"] = {"dist": "uniform", "min": 24, "max": 48}
     c.traffic["engine"]["capacity"] = 128
     c.traffic["check"]["min_tokens"] = 96
@@ -32,7 +32,7 @@ def _quiet(*a, **k):
 def test_sound_run_is_correct():
     res, _ = run.execute(_cell(), SEED, 1.0, False, log=_quiet)
     assert res["correct"] and res["failed"] == 0
-    assert res["checks"]["max_logit_gap"]["value"] < 0.1
+    assert res["checks"]["mean_logit_gap"]["value"] < tiny.LIMIT / 5
     assert list(res)[-1] == "checks"
     assert res["metrics"]["output_tokens_per_s"]["value"] > 0
 
@@ -42,7 +42,7 @@ def test_fault_reads_not_correct(fault):
     res, _ = run.execute(_cell(), SEED, 1.0, False, log=_quiet,
                          wrap_runtime=faults.FAULTS[fault])
     assert not res["correct"]
-    assert res["checks"]["max_logit_gap"]["value"] > 1.0
+    assert res["checks"]["mean_logit_gap"]["value"] > 2 * tiny.LIMIT
 
 
 def test_open_loop_run():

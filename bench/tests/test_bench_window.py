@@ -1,12 +1,12 @@
-"""The timed window: a closed loop is primed before it opens, only what
-falls inside it is counted, and the host-time reader leaves out the
-waits for the device."""
+"""The timed window: a closed loop is primed before it opens (each first
+request to its first decoded token), only what falls inside it is
+counted, and the host-time reader leaves out the waits for the device."""
 from types import SimpleNamespace
 
 import pytest
 
 import tiny
-from harness import serve
+from harness import check, serve
 from harness.spec import load_reader
 from harness.traffic import Traffic
 
@@ -34,7 +34,7 @@ def test_closed_loop_is_primed_before_the_window(window):
     c, w = window
     first = w.recs[:c.traffic["clients"]]
     assert all(rc.due < w.t0 and rc.req.first_token_at < w.t0
-               for rc in first)
+               and rc.req.token_times[0] < w.t0 for rc in first)
     assert all(rc.due >= w.t0 for rc in w.recs[len(first):])
     assert w.tick_contexts and all(
         len(ctx) == c.traffic["clients"] for ctx in w.tick_contexts[:1])
@@ -53,6 +53,18 @@ def test_only_what_falls_inside_the_window_counts(window):
     assert before >= 1 and e2e["_tokens"] == inside
     assert e2e["output_tokens_per_s"] == inside / 1.0
     assert 0 < e2e["itl_p99_s"] < 1.0
+
+
+def test_sample_holds_the_longest_and_every_slot_at_the_close(window):
+    c, w = window
+    assert len(w.held) == c.traffic["clients"]
+    reqs = [rc.req for rc in w.recs]
+    picked = check.sample(reqs, SEED, 1, w.held)
+    longest = max(reqs, key=lambda r: len(r.generated))
+    assert picked[0] is longest and len(picked) <= len(w.held) + 1
+    assert all(any(p is r for p in picked) for r in w.held)
+    more = check.sample(reqs, SEED, 10**6, w.held)
+    assert len(more) == sum(1 for r in reqs if r.generated)
 
 
 def test_engine_host_time_leaves_out_waits_and_admissions():

@@ -31,11 +31,19 @@ MIX = {
 }
 
 
-def cell(tp: int = 1, tied: bool = True, limit: float = 0.5) -> Cell:
+# The tiny cells' limit on the gap the real cells compare, set from their
+# own CPU readings: sound runs read 0 to 9.8e-4 (twelve seeds of the fault
+# tests' cell, four of the four-device chat-shaped cell, three of the
+# four-device exchange cell), the planted faults 0.117 to 2.71 (three
+# seeds each).
+LIMIT = 0.01
+
+
+def cell(tp: int = 1, tied: bool = True) -> Cell:
     config = dict(CONFIG, tie_word_embeddings=tied,
                   deployment={"tensor_parallel": tp})
     return Cell(name="tiny", chips=tp, config=config, traffic=dict(MIX),
-                limits={"max_logit_gap": limit}, end_to_end=[
+                limits={"mean_logit_gap": LIMIT}, end_to_end=[
                     {"name": n, "unit": u} for n, u in (
                         ("output_tokens_per_s", "tokens/s"),
                         ("itl_p99_s", "s"), ("setup_s", "s"))],
